@@ -250,6 +250,8 @@ def bench_tables(rows: list[str]) -> None:
 
 
 def main() -> None:
+    from repro.utils.compile_cache import use_compile_cache
+    use_compile_cache()
     rows = []
     rows += bench_serving_rows()
     rows += bench_load_rows()

@@ -195,7 +195,7 @@ def make_multihost_predict(mesh: jax.sharding.Mesh, host_axis: str,
     over BOTH axes (``P((host_axis, model_axis))``); shard s lives on
     host ``s // shards_per_host`` — build the stack with
     ``serve.heads.shard_index(..., shard_range=...)`` plus
-    ``compat.make_global_array`` so no process materializes remote
+    ``jax.make_array_from_process_local_data`` so no process materializes remote
     shards.  q and the outputs are replicated.  On a mesh whose host
     axis is 1 the merge reduces to the flat single-stage path
     bit-identically.

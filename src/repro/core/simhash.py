@@ -63,8 +63,14 @@ _unit = unit   # historical private name
 
 
 def hash_bits(x: jax.Array, theta: jax.Array) -> jax.Array:
-    """Hard hash bits ``sign(theta^T x) > 0`` -> bool ``[..., K*L]``."""
-    return (_unit(x) @ theta.astype(jnp.float32)) > 0
+    """Hard hash bits ``sign(theta^T x) > 0`` -> bool ``[..., K*L]``.
+
+    The hash is defined at fp32 precision, as the kernels compute it: a
+    TPU matmul at default precision rounds its operands to bf16, and
+    neurons hashed that way at index build would disagree with queries
+    hashed in fp32 whenever a score is near zero."""
+    return jnp.matmul(_unit(x), theta.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST) > 0
 
 
 def soft_codes(x: jax.Array, theta: jax.Array) -> jax.Array:

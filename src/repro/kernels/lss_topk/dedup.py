@@ -19,10 +19,10 @@ algorithms implement it, selected through the registry strategy knob
     each id run.  Carrying the original position as the tie-break key
     makes the sort stable, so "first occurrence" means exactly what the
     quadratic mask means (lower index wins) and the two strategies are
-    bit-identical end to end.  The network is expressed as static
-    reshape / slice / select steps (power-of-two stage sizes, no
-    gathers), so the same code path serves the jnp ref and the Pallas
-    kernel body.
+    bit-identical end to end.  The network is expressed as static lane
+    rotations and selects (power-of-two stage sizes, no gathers, no
+    reshapes), so the same code path serves the jnp ref (``jnp.roll``)
+    and the Pallas kernel body (``pltpu.roll``).
 
 Auto-selection (``resolve_dedup``): ``quadratic`` up to
 :func:`dedup_auto_threshold` candidates, ``bitonic`` beyond.  The
@@ -122,45 +122,55 @@ def _ceil_pow2(n: int) -> int:
     return 1 << max(n - 1, 1).bit_length() if n & (n - 1) else max(n, 2)
 
 
-def _compare_exchange(arrays: tuple[jax.Array, ...], j: int, k: int
+def _neighbour(a: jax.Array, s: int, ahead: jax.Array, roll) -> jax.Array:
+    """``a[..., i + s]`` where ``ahead`` holds and ``a[..., i - s]``
+    elsewhere (lane indices mod n), by two static lane rotations.
+    Callers fold :func:`_roll_is_jnp` into ``ahead``, so the network
+    does not rest on the rotation's sign convention."""
+    n = a.shape[-1]
+    ax = a.ndim - 1
+    return jnp.where(ahead, roll(a, n - s, ax), roll(a, s, ax))
+
+
+def _roll_is_jnp(lane: jax.Array, roll) -> jax.Array:
+    """True (everywhere) iff ``roll`` follows ``jnp.roll``:
+    ``roll(a, 1)[i] == a[i - 1]``."""
+    n = lane.shape[-1]
+    return roll(lane, 1, lane.ndim - 1) == (lane + n - 1) % n
+
+
+def _compare_exchange(arrays: tuple[jax.Array, ...], j: int, k: int,
+                      lane: jax.Array, forward: jax.Array, roll
                       ) -> tuple[jax.Array, ...]:
     """One bitonic substage: compare-exchange elements at XOR-distance
     ``j`` inside stage ``k``, ordering by the lexicographic (id, pos) key
     held in ``arrays[0:2]``.
 
-    Partners ``i`` and ``i ^ j`` are exposed by a static reshape to
-    ``[..., n/(2j), 2, j]`` (j is a power of two), so the whole substage
-    is slice/select/stack — no gathers, VPU-friendly in a kernel body.
+    Lane ``i``'s partner ``i ^ j`` is ``i + j`` when bit ``j`` of ``i``
+    is clear and ``i - j`` when it is set, so two static lane rotations
+    and a select expose it: no gathers and no rank-changing reshapes,
+    which is what lets Mosaic lay the network out on the VPU.
     """
+    low = (lane & j) == 0                     # i is the pair's lower lane
+    ahead = (low & forward) | (~low & ~forward)
+
+    def partner(a):
+        return _neighbour(a, j, ahead, roll)
+
     keys, pos = arrays[0], arrays[1]
-    n = keys.shape[-1]
-    lead = keys.shape[:-1]
-
-    def halves(a):
-        s = a.reshape(lead + (n // (2 * j), 2, j))
-        return s[..., 0, :], s[..., 1, :]
-
-    kl, kr = halves(keys)
-    pl_, pr = halves(pos)
-    # ascending iff (i & k) == 0 — constant across each 2j-block because
-    # 2j divides k, so it reduces to a per-block parity of (block*2j)//k
-    blk = jnp.arange(n // (2 * j), dtype=jnp.int32)
-    asc = ((blk * (2 * j)) & k) == 0                         # [n/(2j)]
-    asc = asc.reshape((1,) * len(lead) + (n // (2 * j), 1))
-    swap = (kl > kr) | ((kl == kr) & (pl_ > pr))             # asc violation
-    swap = jnp.where(asc, swap, ~swap)
-
-    def merge(a):
-        lo, hi = halves(a)
-        nlo = jnp.where(swap, hi, lo)
-        nhi = jnp.where(swap, lo, hi)
-        return jnp.stack([nlo, nhi], axis=-2).reshape(lead + (n,))
-
-    return tuple(merge(a) for a in arrays)
+    pk, pp = partner(keys), partner(pos)
+    self_less = (keys < pk) | ((keys == pk) & (pos < pp))
+    # ascending iff (i & k) == 0: there the lower lane keeps the minimum;
+    # descending blocks flip it.  Keys are distinct (pos is unique).
+    asc = (lane & k) == 0
+    want_min = (low & asc) | (~low & ~asc)
+    keep = (self_less & want_min) | (~self_less & ~want_min)
+    return tuple(jnp.where(keep, a, partner(a)) for a in arrays)
 
 
 def bitonic_sort_by_id_pos(ids: jax.Array, pos: jax.Array,
-                           *payload: jax.Array) -> tuple[jax.Array, ...]:
+                           *payload: jax.Array, roll=jnp.roll
+                           ) -> tuple[jax.Array, ...]:
     """Sort ``(ids, pos, *payload)`` along the last axis ascending by the
     (id, pos) pair with a bitonic network.
 
@@ -169,21 +179,24 @@ def bitonic_sort_by_id_pos(ids: jax.Array, pos: jax.Array,
     come out in original-position order, which is exactly the stable
     lower-index-wins contract the dedup + top-k epilogue needs.
     O(log^2 n) substages, each O(n) work, statically unrolled.
+    ``roll`` is ``jnp.roll`` or, inside a Pallas kernel, ``pltpu.roll``.
     """
     n = ids.shape[-1]
     assert n >= 2 and n & (n - 1) == 0, f"need pow2 length, got {n}"
     arrays = (ids, pos) + payload
+    lane = jax.lax.broadcasted_iota(jnp.int32, ids.shape, ids.ndim - 1)
+    forward = _roll_is_jnp(lane, roll)
     k = 2
     while k <= n:
         j = k // 2
         while j >= 1:
-            arrays = _compare_exchange(arrays, j, k)
+            arrays = _compare_exchange(arrays, j, k, lane, forward, roll)
             j //= 2
         k *= 2
     return arrays
 
 
-def sorted_dedup(ids: jax.Array, logits: jax.Array
+def sorted_dedup(ids: jax.Array, logits: jax.Array, roll=jnp.roll
                  ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Bitonic-sort (id, pos, logit) rows and mark first occurrences.
 
@@ -204,10 +217,10 @@ def sorted_dedup(ids: jax.Array, logits: jax.Array
         pad = [(0, 0)] * len(lead) + [(0, n - c)]
         ids = jnp.pad(ids, pad, constant_values=INT32_MAX)
         logits = jnp.pad(logits, pad, constant_values=0.0)
-    sids, spos, slog = bitonic_sort_by_id_pos(ids, pos, logits)
-    new_run = jnp.concatenate(
-        [jnp.ones(lead + (1,), bool), sids[..., 1:] != sids[..., :-1]],
-        axis=-1)
+    sids, spos, slog = bitonic_sort_by_id_pos(ids, pos, logits, roll=roll)
+    lane = jax.lax.broadcasted_iota(jnp.int32, sids.shape, len(lead))
+    prev = _neighbour(sids, 1, ~_roll_is_jnp(lane, roll), roll)  # [i - 1]
+    new_run = (lane == 0) | (sids != prev)
     first = new_run & (sids >= 0) & (sids != INT32_MAX)
     return sids, spos, slog, first
 

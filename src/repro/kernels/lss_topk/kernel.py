@@ -79,51 +79,77 @@ NEG_INF = -1e30   # matches repro.core.lss.NEG_INF (kept import-free)
 
 DEFAULT_BLOCK_Q = 8   # MXU-friendly query-tile rows per grid step
 
+# f32 operands stay f32 on the MXU: hash signs and logits match the
+# jnp oracle under jax.default_matmul_precision("highest")
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _set_col(out, col, i, val):
+    """``out[:, i] = val`` as a lane select (Mosaic has no narrow
+    unaligned concatenates or scalar stores; a select lowers anywhere)."""
+    return jnp.where(col == i, val, out)
+
+
+def _row_to_col(x_row):
+    """``[1, n] -> [n, 1]`` without a transpose: a diagonal select plus a
+    lane reduction, which adds only exact zeros (Mosaic cannot cast a
+    lane vector to a sublane vector)."""
+    n = x_row.shape[1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    return jnp.sum(jnp.where(row == col, x_row, jnp.zeros((), x_row.dtype)),
+                   axis=1, keepdims=True)
+
 
 def _topk_quadratic_row(cand_row, logits_row, top_k):
     """Original-order dedup + top-k for one ``[1, C]`` candidate row.
     Returns (top_l [1,k], top_i [1,k], sample [1,1]).
 
-    The mask math intentionally restates ``dedup.dedup_mask_quadratic``
-    in strictly 2-D form: the shared helper builds a batched
-    ``[..., C, C]`` compare, and rank-3 intermediates don't lower well
-    in Mosaic — the kernel keeps every array at the ``[C, C]`` /
-    ``[1, C]`` shapes the pre-blocking kernel already compiled."""
+    The mask math restates ``dedup.dedup_mask_quadratic`` in strictly
+    2-D, transpose-free form: the ids' column copy comes from
+    :func:`_row_to_col`, and "no earlier equal id" is a sublane
+    reduction of the ``[C, C]`` compare, which lands directly in
+    ``[1, C]`` row form."""
     c = cand_row.shape[1]
-    eq = cand_row.T == cand_row                               # [C, C]
     row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    n_earlier = jnp.sum((eq & (col < row)).astype(jnp.int32),
-                        axis=1, keepdims=True)                # [C, 1]
-    valid = ((n_earlier == 0) & (cand_row.T >= 0)).T          # [1, C]
+    eq = _row_to_col(cand_row) == cand_row                    # [C, C]
+    n_earlier = jnp.sum((eq & (row < col)).astype(jnp.int32),
+                        axis=0, keepdims=True)                # [1, C]
+    valid = (n_earlier == 0) & (cand_row >= 0)                # [1, C]
     work = jnp.where(valid, logits_row, NEG_INF)
-    sample = jnp.sum(valid.astype(jnp.int32)).reshape(1, 1)
+    sample = jnp.sum(valid.astype(jnp.int32), axis=1, keepdims=True)
 
     pos = jax.lax.broadcasted_iota(jnp.int32, (1, c), 1)
-    tl, ti = [], []
-    for _ in range(top_k):                    # static unroll over k
+    kcol = jax.lax.broadcasted_iota(jnp.int32, (1, top_k), 1)
+    tl = jnp.zeros((1, top_k), jnp.float32)
+    ti = jnp.zeros((1, top_k), jnp.int32)
+    for i in range(top_k):                    # static unroll over k
         best = jnp.max(work, axis=1, keepdims=True)           # [1, 1]
         first = jnp.min(jnp.where(work == best, pos, c),
                         axis=1, keepdims=True)                # [1, 1]
         sel = pos == first                                    # [1, C]
         cid = jnp.sum(jnp.where(sel, cand_row, 0), axis=1,
                       keepdims=True)                          # [1, 1]
-        tl.append(best)
-        ti.append(jnp.where(best > NEG_INF / 2, cid, -1))
+        tl = _set_col(tl, kcol, i, best)
+        ti = _set_col(ti, kcol, i, jnp.where(best > NEG_INF / 2, cid, -1))
         work = jnp.where(sel, NEG_INF, work)
-    return (jnp.concatenate(tl, axis=1), jnp.concatenate(ti, axis=1),
-            sample)
+    return tl, ti, sample
 
 
 def _topk_bitonic_tile(cand, logits, top_k):
     """Sorted-domain dedup + top-k for a whole ``[Bq, C]`` tile.
     Returns (top_l [Bq,k], top_i [Bq,k], sample [Bq,1])."""
-    sids, spos, slog, first = sorted_dedup(cand, logits)      # [Bq, n]
+    sids, spos, slog, first = sorted_dedup(cand, logits,
+                                           roll=pltpu.roll)   # [Bq, n]
     n = sids.shape[-1]
+    bq = cand.shape[0]
     sample = jnp.sum(first.astype(jnp.int32), axis=1, keepdims=True)
     work = jnp.where(first, slog, NEG_INF)
-    tl, ti = [], []
-    for _ in range(top_k):                    # static unroll over k
+    kcol = jax.lax.broadcasted_iota(jnp.int32, (bq, top_k), 1)
+    tl = jnp.zeros((bq, top_k), jnp.float32)
+    ti = jnp.zeros((bq, top_k), jnp.int32)
+    for i in range(top_k):                    # static unroll over k
         best = jnp.max(work, axis=1, keepdims=True)           # [Bq, 1]
         # ties break on the carried ORIGINAL position — the exact
         # lower-index-wins contract of the quadratic path / lax.top_k
@@ -131,11 +157,10 @@ def _topk_bitonic_tile(cand, logits, top_k):
                            axis=1, keepdims=True)             # [Bq, 1]
         sel = spos == firstpos                                # [Bq, n]
         cid = jnp.sum(jnp.where(sel, sids, 0), axis=1, keepdims=True)
-        tl.append(best)
-        ti.append(jnp.where(best > NEG_INF / 2, cid, -1))
+        tl = _set_col(tl, kcol, i, best)
+        ti = _set_col(ti, kcol, i, jnp.where(best > NEG_INF / 2, cid, -1))
         work = jnp.where(sel, NEG_INF, work)
-    return (jnp.concatenate(tl, axis=1),
-            jnp.concatenate(ti, axis=1).astype(jnp.int32), sample)
+    return tl, ti, sample
 
 
 def _make_kernel(k_bits: int, n_tables: int, top_k: int, cap: int,
@@ -160,16 +185,18 @@ def _make_kernel(k_bits: int, n_tables: int, top_k: int, cap: int,
         norm = jnp.sqrt(jnp.sum(q * q, axis=-1, keepdims=True))
         qn = q / jnp.maximum(norm, 1e-12)
         scores = jnp.matmul(qn, theta_ref[...].astype(jnp.float32),
+                            precision=_HIGHEST,
                             preferred_element_type=jnp.float32)  # [Bq, KL]
         bits = (scores > 0).astype(jnp.float32)
-        packed = jnp.matmul(bits, pack_ref[...],
+        packed = jnp.matmul(bits, pack_ref[...], precision=_HIGHEST,
                             preferred_element_type=jnp.float32)  # [Bq, L]
         buckets = packed.astype(jnp.int32)
 
         # ---- stage 2: double-buffered slab DMA + MXU logits -----------
         # One shared fetch schedule for the tile: Bq*L slab copies
         # through a 2-slot rotating scratch; copy i+1 is started before
-        # copy i is consumed, so DMA overlaps the matmul.
+        # copy i is consumed, so DMA overlaps the matmul.  Ids and scales
+        # are stored [S, 1, P], so one slab's row is a whole-tile DMA.
         n_fetch = block_q * n_tables
 
         def slab_of(idx):
@@ -191,8 +218,11 @@ def _make_kernel(k_bits: int, n_tables: int, top_k: int, cap: int,
 
         for cp in copies(0, 0):
             cp.start()
-        logit_rows = [[None] * n_tables for _ in range(block_q)]
-        id_rows = [[None] * n_tables for _ in range(block_q)]
+        # row b of each table's [Bq, P] tile is filled by fetch (b, t)
+        # with a sublane select, so no 1-row pieces are ever concatenated
+        row = jax.lax.broadcasted_iota(jnp.int32, (block_q, cap), 0)
+        logit_tiles = [jnp.zeros((block_q, cap), jnp.float32)] * n_tables
+        id_tiles = [jnp.zeros((block_q, cap), jnp.int32)] * n_tables
         for idx in range(n_fetch):            # static unroll over Bq*L
             slot = idx % 2
             if idx + 1 < n_fetch:
@@ -205,42 +235,49 @@ def _make_kernel(k_bits: int, n_tables: int, top_k: int, cap: int,
             if quantized:
                 # in-VMEM dequantize: same elementwise op as the
                 # oracle's dequantize_int8_rows, so bit-identical
-                w = w * scale_vmem[slot].reshape(cap, 1)
-            blk = jnp.matmul(q, w.T,
+                w = w * _row_to_col(scale_vmem[slot])          # [P, 1]
+            blk = jnp.matmul(q, w.T, precision=_HIGHEST,
                              preferred_element_type=jnp.float32)  # [Bq, P]
-            logit_rows[b][t] = blk[b:b + 1, :]                # this query's
-            id_rows[b][t] = ids_vmem[slot].reshape(1, cap)
-        logits = jnp.concatenate(
-            [jnp.concatenate(r, axis=1) for r in logit_rows], axis=0)
-        cand = jnp.concatenate(
-            [jnp.concatenate(r, axis=1) for r in id_rows], axis=0)
-        cand_ref[...] = cand                                  # [Bq, C]
+            here = row == b                                   # this query's
+            logit_tiles[t] = jnp.where(here, blk, logit_tiles[t])
+            id_tiles[t] = jnp.where(here, ids_vmem[slot], id_tiles[t])
+        logits = jnp.concatenate(logit_tiles, axis=1)         # [Bq, C]
+        cand = jnp.concatenate(id_tiles, axis=1)
+        cand_ref[...] = cand
 
         # ---- stage 3+4: dedup + stable top-k --------------------------
         if dedup == "quadratic":
+            rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, top_k), 0)
+            rows1 = jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+            tl_all = jnp.zeros((block_q, top_k), jnp.float32)
+            ti_all = jnp.zeros((block_q, top_k), jnp.int32)
+            s_all = jnp.zeros((block_q, 1), jnp.int32)
             for b in range(block_q):          # static unroll over the tile
                 tl, ti, sample = _topk_quadratic_row(
                     cand[b:b + 1], logits[b:b + 1], top_k)
-                top_l_ref[b, :] = tl[0, :]
-                top_i_ref[b, :] = ti[0, :]
-                sample_ref[b, 0] = sample[0, 0]
+                tl_all = jnp.where(rows == b, tl, tl_all)
+                ti_all = jnp.where(rows == b, ti, ti_all)
+                s_all = jnp.where(rows1 == b, sample, s_all)
         else:
-            tl, ti, sample = _topk_bitonic_tile(cand, logits, top_k)
-            top_l_ref[...] = tl
-            top_i_ref[...] = ti
-            sample_ref[...] = sample
+            tl_all, ti_all, s_all = _topk_bitonic_tile(cand, logits, top_k)
+        top_l_ref[...] = tl_all
+        top_i_ref[...] = ti_all
+        sample_ref[...] = s_all
 
     return kernel
 
 
 @functools.partial(jax.jit, static_argnames=("k_bits", "n_tables", "top_k",
                                              "block_q", "dedup",
+                                             "vmem_limit_bytes",
                                              "interpret"))
 def lss_topk_pallas(q_aug: jax.Array, theta: jax.Array, tids_flat: jax.Array,
                     w_flat: jax.Array, scales_flat: jax.Array | None = None,
                     *, k_bits: int, n_tables: int,
                     top_k: int, block_q: int = DEFAULT_BLOCK_Q,
-                    dedup: str = "quadratic", interpret: bool = False
+                    dedup: str = "quadratic",
+                    vmem_limit_bytes: int | None = None,
+                    interpret: bool = False
                     ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Fused retrieve->score->top-k over ``[block_q, d]`` query tiles.
 
@@ -248,13 +285,18 @@ def lss_topk_pallas(q_aug: jax.Array, theta: jax.Array, tids_flat: jax.Array,
       q_aug:     ``[B, d]`` augmented queries, B a multiple of
                  ``block_q`` (``ops.py`` pads B; pads d on TPU).
       theta:     ``[d, K*L]`` hyperplanes.
-      tids_flat: int32 ``[S, P]`` flattened bucket-major ids (S = L*2^K).
+      tids_flat: int32 ``[S, 1, P]`` flattened bucket-major ids
+                 (S = L*2^K; the unit axis makes one slab's ids a whole
+                 tile for the DMA).
       w_flat:    ``[S, P, d]`` flattened bucket-major slabs
                  (fp32 | bf16 | int8 storage).
-      scales_flat: fp32 ``[S, P]`` per-neuron-row scales — required iff
+      scales_flat: fp32 ``[S, 1, P]`` per-neuron-row scales — required iff
                  ``w_flat`` is int8 (``lss_topk.slab_dtype = int8``).
       block_q:   query rows per grid step (``grid=(B/block_q,)``).
       dedup:     ``quadratic`` | ``bitonic`` (resolved by ``ops.py``).
+      vmem_limit_bytes: scoped VMEM the kernel asks Mosaic for (None =
+                 the chip's default; ``ops.py`` derives it from
+                 ``lss_topk_vmem_bytes``).
 
     Returns:
       (top_logits [B,k], top_ids [B,k], sample [B,1], cand_ids [B, L*P]).
@@ -273,7 +315,8 @@ def lss_topk_pallas(q_aug: jax.Array, theta: jax.Array, tids_flat: jax.Array,
     assert quantized == (scales_flat is not None), \
         "int8 slabs require scales_flat (and only int8 slabs take one)"
     if quantized:
-        assert scales_flat.shape == (n_slabs, cap), scales_flat.shape
+        assert scales_flat.shape == (n_slabs, 1, cap), scales_flat.shape
+    assert tids_flat.shape == (n_slabs, 1, cap), tids_flat.shape
 
     # constant pack matrix: pack[t*K + j, t] = 2^j (exact in fp32)
     eye = jnp.eye(n_tables, dtype=jnp.float32)
@@ -284,17 +327,17 @@ def lss_topk_pallas(q_aug: jax.Array, theta: jax.Array, tids_flat: jax.Array,
         pl.BlockSpec((block_q, d), lambda b: (b, 0)),
         pl.BlockSpec((d, kl), lambda b: (0, 0)),
         pl.BlockSpec((kl, n_tables), lambda b: (0, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),     # ids stay in HBM
-        pl.BlockSpec(memory_space=pltpu.ANY),     # slabs stay in HBM
+        pl.BlockSpec(memory_space=pl.ANY),     # ids stay in HBM
+        pl.BlockSpec(memory_space=pl.ANY),     # slabs stay in HBM
     ]
     scratch = [
         pltpu.VMEM((2, cap, d), w_flat.dtype),    # double-buffered
-        pltpu.VMEM((2, cap), jnp.int32),
+        pltpu.VMEM((2, 1, cap), jnp.int32),
     ]
     operands = [q_aug, theta, pack, tids_flat, w_flat]
     if quantized:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))  # scales too
-        scratch.append(pltpu.VMEM((2, cap), jnp.float32))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))  # scales too
+        scratch.append(pltpu.VMEM((2, 1, cap), jnp.float32))
         operands.append(scales_flat)
     scratch += [pltpu.SemaphoreType.DMA((2,))] * (3 if quantized else 2)
 
@@ -316,5 +359,7 @@ def lss_topk_pallas(q_aug: jax.Array, theta: jax.Array, tids_flat: jax.Array,
             jax.ShapeDtypeStruct((bsz, n_cand), jnp.int32),
         ],
         scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )(*operands)

@@ -50,6 +50,9 @@ lss_topk_op.register_impl("ref", lss_topk_ref)
 # Practical per-core VMEM budget for the kernel's working set (the full
 # VMEM is ~16 MiB; leave headroom for the compiler's own staging).
 VMEM_BUDGET_BYTES = 12 * 2 ** 20
+# Scoped VMEM Mosaic gives a kernel unless it asks for more (16 MiB on
+# v5e, whose cores hold 128 MiB).
+SCOPED_VMEM_DEFAULT_BYTES = 16 * 2 ** 20
 
 BLOCK_Q_ENV_VAR = "REPRO_LSS_BLOCK_Q"
 
@@ -107,6 +110,13 @@ def lss_topk_vmem_bytes(n_candidates: int, d: int, cap: int, *,
     return fixed + slabs + tiles + dedup_ws
 
 
+def lss_topk_vmem_limit(est_bytes: int) -> int:
+    """The scoped VMEM limit the kernel requests for an estimated working
+    set: the default, or the estimate plus a quarter for Mosaic's own
+    staging when that is larger."""
+    return max(SCOPED_VMEM_DEFAULT_BYTES, est_bytes + est_bytes // 4)
+
+
 @functools.lru_cache(maxsize=None)
 def _warn_vmem_exceeded(n_candidates: int, d: int, cap: int, block_q: int,
                         dedup: str, slab_dtype: str, est: float) -> None:
@@ -146,9 +156,9 @@ def _pallas_impl(q_aug: jax.Array, theta: jax.Array, table_ids: jax.Array,
     choice = (dedup if dedup is not None
               else dedup_mod.resolve_dedup(None, n_candidates=n_tables * cap))
     bq = effective_block_q(bsz, block_q)
-    tids = table_ids.reshape(n_tables * n_buckets, cap)
+    tids = table_ids.reshape(n_tables * n_buckets, 1, cap)
     w_flat = w_bucketed.reshape(n_tables * n_buckets, cap, d)
-    scales = (w_scale.reshape(n_tables * n_buckets, cap)
+    scales = (w_scale.reshape(n_tables * n_buckets, 1, cap)
               .astype(jnp.float32) if w_scale is not None else None)
     # Query-tile padding applies in BOTH modes (the grid is blocked
     # either way): zero rows hash to some bucket like any query, produce
@@ -171,14 +181,19 @@ def _pallas_impl(q_aug: jax.Array, theta: jax.Array, table_ids: jax.Array,
         if pad_p:
             w_flat = jnp.pad(w_flat, ((0, 0), (0, pad_p), (0, 0)))
             # padded capacity slots must read as empty, not as neuron 0
-            tids = jnp.pad(tids, ((0, 0), (0, pad_p)), constant_values=-1)
+            tids = jnp.pad(tids, ((0, 0), (0, 0), (0, pad_p)),
+                           constant_values=-1)
             if scales is not None:
                 # padded slots hold zero codes; 0 * 0.0 dequantizes to 0
-                scales = jnp.pad(scales, ((0, 0), (0, pad_p)))
+                scales = jnp.pad(scales, ((0, 0), (0, 0), (0, pad_p)))
+    cap_k = cap + pad_p                  # the slab height the kernel sees
+    est = lss_topk_vmem_bytes(
+        n_tables * cap_k, q_aug.shape[1], cap_k, block_q=bq, dedup=choice,
+        kl=theta.shape[1], slab_dtype=slabs_mod.slab_dtype_of(w_flat))
     top_logits, top_ids, sample, cand = lss_topk_pallas(
         q_aug, theta, tids, w_flat, scales, k_bits=k_bits,
         n_tables=n_tables, top_k=top_k, block_q=bq, dedup=choice,
-        interpret=interpret)
+        vmem_limit_bytes=lss_topk_vmem_limit(est), interpret=interpret)
     if pad_b:
         top_logits = top_logits[:bsz]
         top_ids = top_ids[:bsz]

@@ -27,8 +27,8 @@ def _kernel(x_ref, theta_ref, pack_ref, out_ref):
     x = x_ref[...].astype(jnp.float32)          # [TB, d]
     th = theta_ref[...].astype(jnp.float32)     # [d, KL]
     scores = jax.lax.dot_general(
-        x, th, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)     # [TB, KL]
+        x, th, (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)     # [TB, KL], fp32 hash
     bits = (scores > 0).astype(jnp.float32)
     packed = jax.lax.dot_general(
         bits, pack_ref[...], (((1,), (0,)), ((), ())),

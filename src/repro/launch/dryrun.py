@@ -25,7 +25,6 @@ import jax           # noqa: E402
 
 from repro.configs.registry import all_cells, get_config       # noqa: E402
 from repro.launch.mesh import make_production_mesh             # noqa: E402
-from repro.utils import compat                                 # noqa: E402
 from repro.launch.roofline import parse_collectives, \
     roofline_from_terms                                        # noqa: E402
 from repro.launch.steps import build_cell                      # noqa: E402
@@ -40,7 +39,7 @@ def _compile_cell(cell, mesh):
 
 def _measure(compiled, cell, n_dev) -> dict:
     """Per-device corrected (flops, bytes, collective bytes)."""
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     coll = parse_collectives(compiled.as_text(), n_dev)
     return {
         "flops": float(cost.get("flops", 0.0))
@@ -81,7 +80,7 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool,
     rec = {"arch": arch_id, "shape": shape_name,
            "mesh": "2x16x16" if multi_pod else "16x16"}
     spec = get_config(arch_id)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if spec.family == "lm":
             full_l = spec.model_cfg.n_layers
             cell = build_cell(arch_id, shape_name, mesh, lm_impl="scan")

@@ -19,13 +19,13 @@ from repro.utils import compat
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(
+    return jax.make_mesh(
         shape, axes, axis_types=compat.auto_axis_types(len(axes)))
 
 
 def make_debug_mesh(shape=(2, 2), axes=("data", "model")) -> jax.sharding.Mesh:
     """Small mesh for CI tests (XLA_FLAGS host-device-count >= prod(shape))."""
-    return compat.make_mesh(
+    return jax.make_mesh(
         shape, axes, axis_types=compat.auto_axis_types(len(axes)))
 
 

@@ -24,7 +24,6 @@ import re
 from typing import NamedTuple
 
 from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
-from repro.utils import compat
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -137,7 +136,7 @@ def analyze(compiled, n_devices: int, model_flops: float,
     corrected at an assumed 100 FLOP/B intensity for those regions
     (fused online-softmax tiles are compute-leaning; documented
     approximation in EXPERIMENTS.md)."""
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     flops = float(cost.get("flops", 0.0)) + flops_correction / n_devices
     bts = float(cost.get("bytes accessed", 0.0)) \
         + flops_correction / n_devices / 100.0

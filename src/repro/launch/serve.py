@@ -1,5 +1,6 @@
-"""Serving launcher: load (or train briefly) an LM, fit the LSS head,
-then serve one of three modes:
+"""Serving launcher: build an LM from a seed (``--train-steps 0``) or
+train it briefly, fit the LSS head (hash geometry from the registry
+config), then serve one of three modes:
 
   * ``--mode generate`` (default) — blocking batched decode through the
     unified serving engine (``--runtime async`` instead serves open-loop
@@ -19,6 +20,9 @@ seconds after serving finishes so a one-shot scrape (CI) always lands.
 through the online label-recall auditor (``lss_audit_recall_at_k``;
 also settable via ``$REPRO_OBS_AUDIT_RATE``).
 
+The process exits non-zero when any request or session failed for a
+reason other than an explicit shed (full queue, missed deadline).
+
     python -m repro.launch.serve --arch qwen2-0.5b --reduced \
         --batch 16 --steps 32 [--head full|lss|lss-sharded] \
         [--runtime async --qps 500 --deadline-ms 50] \
@@ -35,7 +39,9 @@ def main() -> None:
     ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--batch", type=int, default=16)
     ap.add_argument("--steps", type=int, default=32)
-    ap.add_argument("--train-steps", type=int, default=150)
+    ap.add_argument("--train-steps", type=int, default=150,
+                    help="LM training steps before serving; 0 serves "
+                         "seeded random weights (no trainer)")
     ap.add_argument("--head", choices=("full", "lss", "lss-sharded"),
                     default="lss")
     ap.add_argument("--impl", choices=("ref", "pallas", "pallas_interpret"),
@@ -117,6 +123,9 @@ def main() -> None:
     args = ap.parse_args()
     head = "full" if args.no_lss else args.head
 
+    from repro.utils.compile_cache import use_compile_cache
+    use_compile_cache()
+
     server = None
     if args.metrics_port is not None:
         from repro.obs.export import MetricsServer
@@ -165,11 +174,9 @@ def main() -> None:
     from repro.configs.registry import get_config
     from repro.configs.reduced import reduced_model_cfg
     from repro.core.lss import LSSConfig
-    from repro.data.pipeline import ShardedBatchIterator
     from repro.data.synthetic import lm_dataset
     from repro.models import transformer as T
     from repro.serve.engine import LMDecoder
-    from repro.train.trainer import TrainConfig, Trainer
 
     spec = get_config(args.arch)
     cfg = reduced_model_cfg(args.arch) if args.reduced else spec.model_cfg
@@ -177,22 +184,30 @@ def main() -> None:
                        else cfg.vocab)
 
     toks = lm_dataset(0, 150_000, cfg.vocab, 33)
-    tc = TrainConfig(lr=3e-3, warmup_steps=15,
-                     total_steps=args.train_steps, ckpt_every=10 ** 9)
-    tr = Trainer(lambda p, b: T.lm_loss(p, b, cfg),
-                 lambda k: T.init_params(k, cfg), tc)
-    it = ShardedBatchIterator({"tokens": toks[:, :-1],
-                               "labels": toks[:, 1:]}, 64)
-    state, _ = tr.fit(jax.random.PRNGKey(0), it, args.train_steps,
-                      log_every=10 ** 9)
+    if args.train_steps > 0:
+        from repro.data.pipeline import ShardedBatchIterator
+        from repro.train.trainer import TrainConfig, Trainer
+        tc = TrainConfig(lr=3e-3, warmup_steps=15,
+                         total_steps=args.train_steps, ckpt_every=10 ** 9)
+        tr = Trainer(lambda p, b: T.lm_loss(p, b, cfg),
+                     lambda k: T.init_params(k, cfg), tc)
+        it = ShardedBatchIterator({"tokens": toks[:, :-1],
+                                   "labels": toks[:, 1:]}, 64)
+        state, _ = tr.fit(jax.random.PRNGKey(0), it, args.train_steps,
+                          log_every=10 ** 9)
+        params = state.params
+    else:
+        params = T.init_params(jax.random.PRNGKey(0), cfg)
 
-    lss_cfg = LSSConfig(k_bits=6, n_tables=1, iul_epochs=4,
-                        iul_inner_steps=8, iul_lr=0.02)
+    # hash geometry (k_bits, n_tables) from the registry config; a brief
+    # IUL schedule so the fit stays a start-up cost
+    lss_cfg = (spec.lss or LSSConfig())._replace(
+        iul_epochs=4, iul_inner_steps=8, iul_lr=0.02)
     # decode mode: --streams slots; generate mode: one slot per prompt
     # row so the batch decodes in a single wave, like the pre-streaming
     # loop.  Pool width covers the warm call's 2-step floor.
     n_slots = args.streams if args.mode == "decode" else args.batch
-    dec = LMDecoder(state.params, cfg, lss_cfg, impl=args.impl,
+    dec = LMDecoder(params, cfg, lss_cfg, impl=args.impl,
                     max_streams=n_slots,
                     max_len=16 + max(args.steps, 2), dedup=args.dedup,
                     slab_dtype=args.slab_dtype, spmd=ctx)
@@ -215,6 +230,7 @@ def main() -> None:
               f"{rcfg.probation_s}s, rollback delta "
               f"{rcfg.rollback_delta}")
 
+    n_failed = 0
     try:
         if ctx is not None and not ctx.is_leader:
             # followers mirrored the (deterministic) train + fit above,
@@ -223,9 +239,9 @@ def main() -> None:
             n = follower_loop(dec.engine, ctx, decoder=dec)
             print(f"follower {ctx.process_id}: {n} ops served")
         elif args.mode == "decode":
-            serve_decode(dec, toks, head, args)
+            n_failed = serve_decode(dec, toks, head, args)
         elif args.runtime == "async":
-            serve_async(dec, prompt, head, args)
+            n_failed = serve_async(dec, prompt, head, args)
         elif ctx is not None:
             from repro.serve.multihost import leader_generate
             out = leader_generate(ctx, dec, prompt, args.steps, head)
@@ -256,11 +272,22 @@ def main() -> None:
         finally:
             if server is not None:
                 server.close()
+    if n_failed:
+        raise SystemExit(f"{n_failed} requests or sessions failed")
 
 
-def serve_decode(dec, toks, head: str, args) -> None:
+def _n_failed(excs) -> int:
+    """Failures among resolved futures/streams, explicit sheds aside."""
+    from repro.serve.runtime.future import (DeadlineExceededError,
+                                            QueueFullError)
+    return sum(e is not None and not isinstance(
+        e, (DeadlineExceededError, QueueFullError)) for e in excs)
+
+
+def serve_decode(dec, toks, head: str, args) -> int:
     """Streaming decode: open-loop decode SESSIONS through the
-    AsyncRuntime at --qps sessions/s, --streams concurrent slots."""
+    AsyncRuntime at --qps sessions/s, --streams concurrent slots.
+    Returns the number of sessions that failed (sheds aside)."""
     import numpy as np
     from repro.serve import AsyncRuntime
     from repro.serve.runtime import submit_decode_open_loop
@@ -285,7 +312,8 @@ def serve_decode(dec, toks, head: str, args) -> None:
             rt, list(prompts), args.qps, max_new_tokens=args.steps, seed=0)
         rt.drain(timeout=600.0)
         s = rt.stats()
-    ok = sum(st.exception(timeout=1.0) is None for st in streams)
+    excs = [st.exception(timeout=1.0) for st in streams]
+    ok = sum(e is None for e in excs)
     print(f"streaming decode: head={head} streams={args.streams} "
           f"qps={args.qps} {ok}/{len(streams)} sessions served, "
           f"{s.n_decode_tokens} tokens")
@@ -297,12 +325,14 @@ def serve_decode(dec, toks, head: str, args) -> None:
           f"slot occupancy={s.decode_slot_occupancy:.2f}")
     print(f"  shed: queue={s.n_shed_queue} deadline={s.n_shed_deadline}")
     print(f"engine compiles (head, shape): {dec.engine.compile_counts}")
+    return _n_failed(excs)
 
 
-def serve_async(dec, prompt, head: str, args) -> None:
+def serve_async(dec, prompt, head: str, args) -> int:
     """Open-loop next-token scoring: prefill once, then submit each
     sequence's final hidden state as an independent rank request through
-    the AsyncRuntime at the offered QPS."""
+    the AsyncRuntime at the offered QPS.  Returns the number of requests
+    that failed (sheds aside)."""
     import jax.numpy as jnp
     import numpy as np
     from repro.serve.runtime import AsyncRuntime, submit_open_loop
@@ -328,7 +358,8 @@ def serve_async(dec, prompt, head: str, args) -> None:
         futs, _ = submit_open_loop(rt, reqs, args.qps, seed=0)
         rt.drain(timeout=300.0)
         s = rt.stats()
-    ok = sum(f.exception() is None for f in futs)
+    excs = [f.exception() for f in futs]
+    ok = sum(e is None for e in excs)
     aud = dec.engine.auditor
     if aud is not None:
         aud.drain()
@@ -342,6 +373,7 @@ def serve_async(dec, prompt, head: str, args) -> None:
     print(f"  batches={s.n_batches} occupancy={s.avg_batch_occupancy:.2f} "
           f"shed: queue={s.n_shed_queue} deadline={s.n_shed_deadline}")
     print(f"engine compiles (head, bucket): {dec.engine.compile_counts}")
+    return _n_failed(excs)
 
 
 if __name__ == "__main__":

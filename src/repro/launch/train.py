@@ -53,7 +53,7 @@ def main() -> None:
     if args.mesh:
         shape = tuple(int(x) for x in args.mesh.split("x"))
         from repro.utils import compat
-        mesh = compat.make_mesh(shape, ("data", "model")[: len(shape)],
+        mesh = jax.make_mesh(shape, ("data", "model")[: len(shape)],
                                 axis_types=compat.auto_axis_types(len(shape)))
         param_specs = T.param_specs(cfg)
 

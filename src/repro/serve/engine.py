@@ -414,7 +414,7 @@ class Engine:
         if self.spmd is not None:
             return self.spmd.mesh
         if self.mesh is None:
-            self.mesh = compat.make_mesh(
+            self.mesh = jax.make_mesh(
                 (len(jax.devices()),), (self.model_axis,),
                 axis_types=compat.auto_axis_types(1))
         return self.mesh
@@ -442,8 +442,14 @@ class Engine:
             mesh = self._get_mesh()
             tp = mesh.shape[self.model_axis]
             if st.sharded is None:
-                st.sharded = shard_index(self._w_aug, st.index.theta,
-                                         self.lss_cfg, tp)
+                stack, w_stack, m_local = shard_index(
+                    self._w_aug, st.index.theta, self.lss_cfg, tp)
+                # lay shard i on device i of the model axis once, here:
+                # the stacks are step arguments, not program constants
+                on_axis = jax.sharding.NamedSharding(
+                    mesh, jax.sharding.PartitionSpec(self.model_axis))
+                st.sharded = jax.device_put((stack, w_stack), on_axis) + (
+                    m_local,)
             stack, w_stack, m_local = st.sharded
             head = make_sharded_lss_head(stack, w_stack, mesh,
                                          self.lss_cfg, m_local,
@@ -507,25 +513,18 @@ class Engine:
                     kind, None if kind == "full"
                     else self._epoch_state(epoch))
                 embed = self.embed_fn
-                operands = getattr(head, "global_operands", None)
 
                 def raw_step(x, *ops):
                     self.compile_counts[key] = \
                         self.compile_counts.get(key, 0) + 1
                     q = embed(x) if embed is not None else x
-                    if ops:
-                        return head.with_operands(q, *ops)
-                    return head(q)
+                    return head.with_operands(q, *ops)
 
-                jitted = jax.jit(raw_step)
-                if operands is None:
-                    step = jitted
-                else:
-                    # multi-process jit cannot CLOSE OVER the global
-                    # (host, model)-sharded stacks — thread them as
-                    # explicit arguments, keeping the step(x) seam
-                    def step(x, _j=jitted, _ops=operands):
-                        return _j(x, *_ops)
+                # the head's arrays ride as jit arguments, never as
+                # closure constants (see serve.heads)
+                def step(x, _j=jax.jit(raw_step), _ops=head.operands):
+                    return _j(x, *_ops)
+
                 if self.spmd is not None and self.spmd.is_leader:
                     # the SPMD seam: sync rank/flush AND the runtime
                     # dispatcher all fetch from here, so wrapping the
@@ -576,34 +575,35 @@ class Engine:
                 head = self._head(
                     kind, None if kind == "full"
                     else self._epoch_state(epoch))
-                operands = getattr(head, "global_operands", None)
-                n_ops = 0 if operands is None else len(operands)
+                n_ops = len(head.operands)
+                # a fleet's lss-sharded stacks are global arrays: its
+                # step replicates every local operand onto the mesh
+                fleet = self.spmd is not None and kind == "lss-sharded"
 
                 def raw_step(params, tok, *rest):
                     self.compile_counts[key] = \
                         self.compile_counts.get(key, 0) + 1
-                    state = rest[:len(rest) - n_ops] if n_ops else rest
+                    state, ops = rest[:-n_ops], rest[-n_ops:]
                     hidden, k_new, v_new = body(params, tok, *state)
-                    h = hidden.astype(jnp.float32)
-                    if n_ops:
-                        ho = head.with_operands(h, *rest[len(rest) - n_ops:])
-                    else:
-                        ho = head(h)
+                    ho = head.with_operands(hidden.astype(jnp.float32),
+                                            *ops)
                     tok_next = jnp.maximum(ho.ids[:, 0], 0).astype(jnp.int32)
                     return tok_next, ho, k_new, v_new
 
                 donate = ((2, 3) if jax.default_backend() == "tpu"
-                          and not n_ops else ())
+                          and not fleet else ())
                 jitted = jax.jit(raw_step, donate_argnums=donate)
-                if operands is None:
-                    table[key] = jitted
+                if not fleet:
+                    def step(params, tok, *state, _j=jitted,
+                             _ops=head.operands):
+                        return _j(params, tok, *state, *_ops)
+
+                    table[key] = step
                 else:
-                    # same operand threading as _step: the global stacks
-                    # ride as trailing jit arguments, and every local
-                    # operand is promoted to a mesh-replicated global
-                    # array (metadata-only: each process holds the same
-                    # mirrored value) so the fused decode program runs
-                    # SPMD across the fleet
+                    # every local operand is promoted to a
+                    # mesh-replicated global array (metadata-only: each
+                    # process holds the same mirrored value) so the
+                    # fused decode program runs SPMD across the fleet
                     from repro.utils import compat
                     mesh = self.spmd.mesh
                     # params replicate ONCE per weight tree, not per
@@ -618,7 +618,7 @@ class Engine:
                     params_cache: dict = {}
 
                     def step(params, tok, *state, _j=jitted,
-                             _ops=operands):
+                             _ops=head.operands):
                         cached = params_cache.get(id(params))
                         if cached is None or cached[0] is not params:
                             params_cache.clear()

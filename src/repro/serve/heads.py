@@ -12,6 +12,13 @@ output layer for a batch of query embeddings.  Three implementations:
 
 All heads return the same :class:`HeadOutput`, so the engine's batcher,
 metrics, and the LM decode loop are head-agnostic.
+
+Every head also carries its arrays as ``head.operands`` and the
+operand-taking form ``head.with_operands(q, *operands)``.  Jitted steps
+pass the operands as arguments: an array a jitted function closes over
+is baked into the program as a constant (GB-scale for a full-vocab WOL,
+and again in every compile-cache entry), and a multi-process jit cannot
+close over arrays that span other hosts at all.
 """
 
 from __future__ import annotations
@@ -42,18 +49,32 @@ class HeadOutput(NamedTuple):
     cand_ids: jax.Array | None   # [B, C] retrieved set (None: full/sharded)
 
 
+def _with_operands(with_operands: Callable, operands: tuple
+                   ) -> Callable[[jax.Array], HeadOutput]:
+    """The head ``q -> with_operands(q, *operands)``, with both parts
+    exposed for jitted steps (see the module docstring)."""
+
+    def head(q: jax.Array) -> HeadOutput:
+        return with_operands(q, *operands)
+
+    head.operands = operands
+    head.with_operands = with_operands
+    return head
+
+
 def make_full_head(w: jax.Array, b: jax.Array, top_k: int
                    ) -> Callable[[jax.Array], HeadOutput]:
     """Exact WOL: every neuron is scored (sample size == m)."""
     m = w.shape[0]
 
-    def head(q: jax.Array) -> HeadOutput:
+    def with_operands(q: jax.Array, w: jax.Array, b: jax.Array
+                      ) -> HeadOutput:
         logits = q.astype(jnp.float32) @ w.T.astype(jnp.float32) + b
         top, ids = jax.lax.top_k(logits, top_k)
         return HeadOutput(top, ids,
                           jnp.full((q.shape[0],), m, jnp.int32), None)
 
-    return head
+    return _with_operands(with_operands, (w, b))
 
 
 def make_lss_head(index: LSSIndex, w_aug: jax.Array | None, top_k: int,
@@ -67,13 +88,14 @@ def make_lss_head(index: LSSIndex, w_aug: jax.Array | None, top_k: int,
     ``bitonic``; None = auto-select on the candidate count).
     """
 
-    def head(q: jax.Array) -> HeadOutput:
+    def with_operands(q: jax.Array, index: LSSIndex,
+                      w_aug: jax.Array | None) -> HeadOutput:
         out = lss_forward(q.astype(jnp.float32), index, w_aug, top_k,
                           impl=impl, dedup=dedup)
         return HeadOutput(out.top_logits, out.top_ids, out.sample_size,
                           out.cand_ids)
 
-    return head
+    return _with_operands(with_operands, (index, w_aug))
 
 
 def _mask_index_tail(index: LSSIndex, n_valid: int) -> LSSIndex:
@@ -184,12 +206,12 @@ def make_sharded_lss_head(index_stack, w_stack, mesh, cfg: LSSConfig,
     fwd = make_sharded_predict(mesh, model_axis, cfg, m_local, top_k,
                                with_aux=True, impl=impl, dedup=dedup)
 
-    def head(q: jax.Array) -> HeadOutput:
+    def with_operands(q: jax.Array, index_stack, w_stack) -> HeadOutput:
         logits, ids, sample = fwd(q.astype(jnp.float32), index_stack,
                                   w_stack)
         return HeadOutput(logits, ids, sample, None)
 
-    return head
+    return _with_operands(with_operands, (index_stack, w_stack))
 
 
 def make_multihost_lss_head(index_stack, w_stack, mesh, cfg: LSSConfig,
@@ -205,26 +227,15 @@ def make_multihost_lss_head(index_stack, w_stack, mesh, cfg: LSSConfig,
     the whole fleet.  ``index_stack`` leaves are GLOBAL arrays sharded
     ``P((host_axis, model_axis))`` on the leading [n_shards] dim — build
     them with ``shard_index(..., shard_range=...)`` +
-    ``compat.make_global_array``.
+    ``jax.make_array_from_process_local_data``.
     """
     fwd = make_multihost_predict(mesh, host_axis, model_axis, cfg,
                                  m_local, top_k, with_aux=True,
                                  impl=impl, dedup=dedup)
 
-    # Multi-process jit forbids CLOSING OVER arrays spanning
-    # non-addressable devices, so the stacks cannot ride into a jitted
-    # step as closure constants: the head exposes them on
-    # ``head.global_operands`` plus the operand-threading form
-    # ``head.with_operands(q, *operands)``, and Engine._step /
-    # decode_logits pass them as explicit jit arguments instead.
     def with_operands(q: jax.Array, index_stack, w_stack) -> HeadOutput:
         logits, ids, sample = fwd(q.astype(jnp.float32), index_stack,
                                   w_stack)
         return HeadOutput(logits, ids, sample, None)
 
-    def head(q: jax.Array) -> HeadOutput:
-        return with_operands(q, index_stack, w_stack)
-
-    head.global_operands = (index_stack, w_stack)
-    head.with_operands = with_operands
-    return head
+    return _with_operands(with_operands, (index_stack, w_stack))

@@ -163,7 +163,7 @@ class MultihostContext:
 
     @property
     def process_id(self) -> int:
-        return compat.process_index()
+        return jax.process_index()
 
     @property
     def n_processes(self) -> int:
@@ -224,8 +224,8 @@ def assemble_global_stack(ctx: MultihostContext, local_tree, n_shards: int):
 
     def leaf(x):
         x = np.asarray(x)
-        return compat.make_global_array(sharding, x,
-                                        (n_shards,) + x.shape[1:])
+        return jax.make_array_from_process_local_data(
+            sharding, x, (n_shards,) + x.shape[1:])
 
     return jax.tree.map(leaf, local_tree)
 

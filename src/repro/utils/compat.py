@@ -1,108 +1,42 @@
-"""Version-tolerant wrappers for jax APIs that moved between releases.
+"""Sharding and multi-process helpers shared by the serving, launch and
+test code, written against the installed JAX (0.9).
 
-The repo pins jax 0.4.37 (the jaxlib in the image), but the sharding API
-surface it uses was renamed upstream several times:
-
-  * ``jax.sharding.get_abstract_mesh``  -> pre-0.5: thread-resources mesh
-  * ``jax.set_mesh(mesh)`` context      -> pre-0.5: ``with mesh:``
-  * ``jax.shard_map(..., check_vma=)``  -> pre-0.5:
-    ``jax.experimental.shard_map.shard_map(..., check_rep=)``
-  * ``jax.make_mesh(..., axis_types=)`` -> pre-0.5: no ``axis_types``
-  * ``jax.sharding.AxisType``           -> absent pre-0.5
-
-Every call site in the repo goes through these helpers so the same code
-runs on the pinned jax and on current releases.
+  * :func:`auto_axis_types` -- ``AxisType.Auto`` for every mesh axis
+    (``jax.make_mesh`` otherwise makes explicit-sharding axes);
+  * :func:`shard_map` -- ``jax.shard_map`` with the replication check
+    off, the way every call site here uses it;
+  * the ``jax.distributed`` helpers below.
 """
 
 from __future__ import annotations
 
-import inspect
 import os
 
 import jax
 
-__all__ = ["get_abstract_mesh", "set_mesh", "make_mesh", "shard_map",
-           "auto_axis_types", "cost_analysis",
-           "distributed_initialize", "is_distributed", "process_index",
-           "process_count", "make_global_mesh", "make_global_array",
-           "broadcast_one_to_all", "process_allgather",
+__all__ = ["auto_axis_types", "shard_map",
+           "distributed_initialize", "is_distributed",
+           "make_global_mesh", "broadcast_one_to_all", "process_allgather",
            "replicate_global",
            "DIST_COORDINATOR_ENV", "DIST_NUM_PROCESSES_ENV",
            "DIST_PROCESS_ID_ENV"]
 
 
-def get_abstract_mesh():
-    """The mesh of the surrounding ``set_mesh``/``with mesh`` context.
-
-    Returns a mesh object whose ``empty`` attribute is True when no mesh
-    is active (matching ``jax.sharding.get_abstract_mesh`` semantics), or
-    None when no context mechanism exists at all.
-    """
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is None:
-        fn = getattr(jax.sharding, "get_mesh", None)
-    if fn is not None:
-        return fn()
-    try:
-        from jax._src import mesh as _mesh_lib
-        return _mesh_lib.thread_resources.env.physical_mesh
-    except Exception:       # pragma: no cover - very old/new internals
-        return None
-
-
-def set_mesh(mesh):
-    """Context manager activating ``mesh`` for sharding-constraint
-    resolution: ``jax.set_mesh`` when present, else the classic
-    ``with mesh:`` (Mesh is its own context manager pre-0.5)."""
-    fn = getattr(jax, "set_mesh", None)
-    if fn is not None:
-        return fn(mesh)
-    return mesh
-
-
-def auto_axis_types(n_axes: int):
-    """``(AxisType.Auto,) * n`` on jax versions that have AxisType,
-    else None (pre-0.5 meshes are implicitly auto)."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return None if at is None else (at.Auto,) * n_axes
-
-
-def make_mesh(axis_shapes, axis_names, *, axis_types=None, devices=None):
-    """``jax.make_mesh`` dropping kwargs the pinned version lacks."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if axis_types is not None and \
-            "axis_types" in inspect.signature(jax.make_mesh).parameters:
-        kwargs["axis_types"] = axis_types
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict: pre-0.5 jax returned a
-    one-element list of per-program dicts."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+def auto_axis_types(n_axes: int) -> tuple:
+    """``(AxisType.Auto,) * n_axes`` for ``jax.make_mesh``."""
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` (check_vma) or the experimental one (check_rep)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check)
+    """``jax.shard_map`` with ``check_vma=check`` (off by default)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 # ===================================================== multi-process ==
-# jax.distributed moved less than the sharding API, but the pieces a
-# multi-host serving mesh needs still differ across releases (the CPU
-# collectives flag, make_array_from_process_local_data's signature), so
-# every multi-process call site routes through here too.
+# The multi-process pieces a multi-host serving mesh needs: distributed
+# start-up with CPU collectives, the global (host, model) mesh, and
+# moving pytrees between processes.
 
 DIST_COORDINATOR_ENV = "REPRO_DIST_COORDINATOR"
 DIST_NUM_PROCESSES_ENV = "REPRO_DIST_NUM_PROCESSES"
@@ -139,21 +73,11 @@ def distributed_initialize(coordinator_address: str | None = None,
         return _dist_initialized
     if _dist_initialized:
         return True
-    try:
-        # renamed/absent on some releases; non-CPU backends don't need it
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass
+    # cross-process collectives on the CPU backend; others ignore it
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id)
-    try:
-        # the mirrored-decode path runs eager (non-jit) ops on
-        # replicated global arrays in lockstep on every process; jax
-        # guards those behind spmd_mode (flag absent on newer releases)
-        jax.config.update("jax_spmd_mode", "allow_all")
-    except Exception:
-        pass
     _dist_initialized = True
     return True
 
@@ -161,14 +85,6 @@ def distributed_initialize(coordinator_address: str | None = None,
 def is_distributed() -> bool:
     """True iff :func:`distributed_initialize` activated a fleet."""
     return _dist_initialized
-
-
-def process_index() -> int:
-    return jax.process_index()
-
-
-def process_count() -> int:
-    return jax.process_count()
 
 
 def make_global_mesh(axis_names: tuple[str, str] = ("host", "model")
@@ -185,27 +101,6 @@ def make_global_mesh(axis_names: tuple[str, str] = ("host", "model")
     n_proc = jax.process_count()
     grid = np.asarray(devs).reshape(n_proc, len(devs) // n_proc)
     return jax.sharding.Mesh(grid, axis_names)
-
-
-def make_global_array(sharding, local_data, global_shape: tuple
-                      ) -> jax.Array:
-    """A global array from this process's slice of it (leading-axis
-    sharded).  ``jax.make_array_from_process_local_data`` where present,
-    else assembled per-device via ``make_array_from_single_device_arrays``.
-    """
-    fn = getattr(jax, "make_array_from_process_local_data", None)
-    if fn is not None:
-        try:
-            return fn(sharding, local_data, global_shape)
-        except TypeError:       # older signature: no global_shape arg
-            return fn(sharding, local_data)
-    import numpy as np
-    local_devs = [d for d in sharding.mesh.devices.flat
-                  if d.process_index == jax.process_index()]
-    chunks = np.split(np.asarray(local_data), len(local_devs), axis=0)
-    shards = [jax.device_put(c, d) for c, d in zip(chunks, local_devs)]
-    return jax.make_array_from_single_device_arrays(
-        global_shape, sharding, shards)
 
 
 def broadcast_one_to_all(x):
@@ -242,6 +137,6 @@ def replicate_global(tree, mesh) -> object:
             return v
         import numpy as np
         v = np.asarray(v)
-        return make_global_array(sharding, v, v.shape)
+        return jax.make_array_from_process_local_data(sharding, v, v.shape)
 
     return jax.tree.map(leaf, tree)

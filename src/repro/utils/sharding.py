@@ -8,22 +8,21 @@ from typing import Any
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.utils.compat import get_abstract_mesh
 
 __all__ = ["maybe_shard", "named_sharding", "specs_to_shardings"]
 
 
 def _active_mesh_axes() -> tuple[str, ...] | None:
-    mesh = get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return None
     return tuple(mesh.axis_names)
 
 
 def mesh_axis_size(name: str) -> int | None:
     """Size of a mesh axis at trace time, or None outside a mesh."""
-    mesh = get_abstract_mesh()
-    if mesh is None or mesh.empty or name not in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or name not in mesh.axis_names:
         return None
     return mesh.shape[name]
 

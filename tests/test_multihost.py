@@ -78,7 +78,7 @@ from repro.serve.heads import shard_index
 
 w_aug = simhash.augment_neurons(W, None)
 stack, w_stack, m_local = shard_index(w_aug, THETA, CFG, 4)
-mesh = compat.make_mesh((4,), ("model",),
+mesh = jax.make_mesh((4,), ("model",),
                         axis_types=compat.auto_axis_types(1))
 fwd = make_sharded_predict(mesh, "model", CFG, m_local, K, with_aux=True)
 logits, ids, sample = jax.jit(fwd)(Q, stack, w_stack)

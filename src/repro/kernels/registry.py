@@ -45,7 +45,8 @@ import jax
 __all__ = [
     "IMPLS", "ENV_VAR", "KernelOp", "kernel_op", "get_op", "list_ops",
     "resolve_impl", "set_default_impl", "use_impl", "dispatch_log",
-    "dispatch_counts", "last_dispatch", "reset_dispatch_log",
+    "dispatch_count", "dispatch_counts", "last_dispatch",
+    "reset_dispatch_log",
     "KernelStrategy", "kernel_strategy", "get_strategy", "list_strategies",
     "set_default_strategy", "use_strategy",
 ]
@@ -255,13 +256,20 @@ def use_strategy(name: str, choice: str | None):
 
 # ------------------------------------------------------ dispatch records --
 
-def dispatch_log() -> tuple[tuple[str, str], ...]:
-    """All ``(op_name, impl)`` dispatches since the last reset, in order.
+def dispatch_log(start: int = 0) -> tuple[tuple[str, str], ...]:
+    """The ``(op_name, impl)`` dispatches since the last reset, in order,
+    from the ``start``-th on (pair with :func:`dispatch_count` to copy
+    only what a call added).
 
     Recorded at trace time: a jitted caller contributes one entry per
     compilation, not per device invocation.
     """
-    return tuple(_log)
+    return tuple(_log[start:])
+
+
+def dispatch_count() -> int:
+    """How many dispatches the log holds: a cheap mark to diff against."""
+    return len(_log)
 
 
 def dispatch_counts() -> dict[tuple[str, str], int]:

@@ -6,7 +6,8 @@ docs/ARCHITECTURE.md#observability):
   * :mod:`repro.obs.metrics`  — typed counters/gauges/bounded histograms
     in per-component registries, merged by the exporters;
   * :mod:`repro.obs.tracing`  — spans through the serving seams with a
-    bounded ring and chrome://tracing export;
+    bounded ring and chrome://tracing export; scoped spans also land on
+    a running ``jax.profiler`` trace;
   * :mod:`repro.obs.export`   — Prometheus text / JSON snapshot over a
     stdlib ``http.server`` endpoint;
   * :mod:`repro.obs.audit`    — the online label-recall auditor
@@ -14,9 +15,10 @@ docs/ARCHITECTURE.md#observability):
 
 The whole subsystem sits behind one switch: ``REPRO_OBS=0`` (or
 :func:`set_enabled`) makes registries hand out shared no-op metrics and
-:func:`start_span` return the shared no-op span — the "compiled-out"
-baseline the overhead bench measures against.  Components read the
-switch at construction, so toggle *before* building an engine/runtime.
+:func:`start_span` / :func:`span` the shared no-op span (no profiler
+annotation either) — the "compiled-out" baseline the overhead bench
+measures against.  Components read the switch at construction, so
+toggle *before* building an engine/runtime.
 """
 
 from __future__ import annotations
@@ -25,18 +27,18 @@ import os
 
 from repro.obs.metrics import (DEFAULT_RESERVOIR, Counter, Gauge, Histogram,
                                MetricsRegistry, all_registries)
-from repro.obs.tracing import (JAX_PROFILE_ENV, SPAN_STATUSES, TRACE_CAP_ENV,
-                               Span, assert_quiescent, event,
-                               maybe_jax_profile, open_spans, reset_tracer,
-                               start_span, status_from_exc, trace_export)
+from repro.obs.tracing import (NOOP_SCOPE, SPAN_STATUSES, TRACE_CAP_ENV,
+                               Span, assert_quiescent, event, open_spans,
+                               reset_tracer, span, start_span,
+                               status_from_exc, trace_export)
 
 __all__ = [
     "enabled", "set_enabled", "registry", "reset",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "all_registries",
     "DEFAULT_RESERVOIR",
-    "Span", "SPAN_STATUSES", "start_span", "event", "trace_export",
-    "assert_quiescent", "open_spans", "reset_tracer", "status_from_exc",
-    "maybe_jax_profile", "JAX_PROFILE_ENV", "TRACE_CAP_ENV",
+    "Span", "SPAN_STATUSES", "start_span", "span", "NOOP_SCOPE", "event",
+    "trace_export", "assert_quiescent", "open_spans", "reset_tracer",
+    "status_from_exc", "TRACE_CAP_ENV",
     "OBS_ENV", "AUDIT_RATE_ENV",
 ]
 

@@ -2,12 +2,12 @@
 
 A :class:`Span` is one timed unit of work — a scoring request's whole
 submit→complete life, one dispatcher chunk, one decode session, one
-prefill, one scheduler tick — carrying attributes (rid/sid, head kind,
-bucket), point-in-time *events* (join, first token, KV page churn), and
-a terminal *status*.  Spans are deliberately flat (no parent pointers):
-the rid/sid attributes correlate a request span with the chunk/tick
-spans that served it, which is all the life-of-a-request view needs and
-keeps the record cheap enough for the hot path.
+session's admission, one scheduler tick — carrying attributes (rid/sid,
+head kind, bucket), point-in-time *events* (join, first token, KV page
+churn), and a terminal *status*.  Spans are deliberately flat (no
+parent pointers): the rid/sid attributes correlate a request span with
+the chunk/tick spans that served it, which is all the life-of-a-request
+view needs and keeps the record cheap enough for the hot path.
 
 Terminal statuses mirror the runtime's failure taxonomy so every shed
 path is distinguishable in a trace: ``ok``, ``shed_queue``,
@@ -24,10 +24,14 @@ the ring as a chrome://tracing / Perfetto-compatible JSON object
 (``{"traceEvents": [...]}``, complete ``"X"`` events for spans, instant
 ``"i"`` events for point events).
 
-One optional deep hook: :func:`maybe_jax_profile` wraps a block in a
-``jax.profiler`` trace when ``REPRO_OBS_JAX_PROFILE`` names a directory
-— one env var between "spans say the device step is slow" and an XLA
-op-level timeline.
+Scoped spans (:func:`span`, a ``with`` block on one thread) also enter
+a ``jax.profiler.TraceAnnotation`` of the same name: while a profiler
+collects, each is written into the profiler's host plane on the device
+trace's clock, so a gap in device activity is named by the host step
+that held it.  Names are fixed strings (attributes stay in the ring) so
+trace readers match them exactly.  Lifecycle spans that open on one
+thread and close on another (``request``, ``chunk``, ``decode_session``)
+use :func:`start_span` and stay ring-only.
 """
 
 from __future__ import annotations
@@ -37,16 +41,16 @@ import os
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import nullcontext
 
-__all__ = ["Span", "SPAN_STATUSES", "start_span", "event", "trace_export",
-           "assert_quiescent", "open_spans", "reset_tracer",
-           "status_from_exc", "maybe_jax_profile", "JAX_PROFILE_ENV",
-           "TRACE_CAP_ENV"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Span", "SPAN_STATUSES", "start_span", "span", "NOOP_SCOPE",
+           "event", "trace_export", "assert_quiescent", "open_spans",
+           "reset_tracer", "status_from_exc", "TRACE_CAP_ENV"]
 
 SPAN_STATUSES = ("ok", "shed_queue", "shed_deadline", "shed_kv_oom",
                  "closed", "error")
-JAX_PROFILE_ENV = "REPRO_OBS_JAX_PROFILE"
 TRACE_CAP_ENV = "REPRO_OBS_TRACE_CAP"
 
 _EVENTS_PER_SPAN = 64                   # bound per-span event lists too
@@ -224,6 +228,46 @@ def start_span(name: str, **attrs) -> Span | _NoopSpan:
     return _tracer.start(name, attrs)
 
 
+class _Scoped:
+    """A ring span that is also a profiler ``TraceAnnotation`` for the
+    length of a ``with`` block; the block's exception, if any, sets the
+    span's terminal status."""
+
+    __slots__ = ("_span", "_ann")
+
+    def __init__(self, span: Span):
+        self._span = span
+        self._ann = TraceAnnotation(span.name)
+
+    def __enter__(self) -> Span:
+        self._ann.__enter__()
+        return self._span
+
+    def __exit__(self, et, exc, tb) -> bool:
+        self._ann.__exit__(et, exc, tb)
+        if exc is None:
+            self._span.end("ok")
+        else:
+            self._span.end_from_exc(exc)
+        return False
+
+
+# what span() gives with obs off; also for call sites that skip a span
+NOOP_SCOPE = nullcontext(NOOP_SPAN)
+
+
+def span(name: str, **attrs):
+    """``with span(name, **attrs) as s:`` — a span over one host step of
+    one thread, in the ring as :func:`start_span` records it and, while a
+    profiler collects, on the profiler's host plane under ``name``.  An
+    explicit ``s.end(...)`` inside the block sets the status first; the
+    block's end closes it ``ok`` (or by its exception).  No-op when obs
+    is disabled."""
+    if not _enabled():
+        return NOOP_SCOPE
+    return _Scoped(_tracer.start(name, attrs))
+
+
 def event(name: str, **attrs) -> None:
     """Record a process-level instant event (KV page churn, evictions —
     things not owned by any one span)."""
@@ -295,19 +339,3 @@ def trace_export(path: str | None = None, *,
         with open(path, "w") as f:
             json.dump(out, f)
     return out
-
-
-@contextmanager
-def maybe_jax_profile(suffix: str = ""):
-    """When ``$REPRO_OBS_JAX_PROFILE`` names a directory, wrap the block
-    in a ``jax.profiler`` trace written there (XLA op-level timeline,
-    viewable in Perfetto/TensorBoard); otherwise a free no-op.  The one
-    deep-capture hook the tracing layer exposes."""
-    target = os.environ.get(JAX_PROFILE_ENV) or None
-    if not target or not _enabled():
-        yield
-        return
-    import jax
-    with jax.profiler.trace(os.path.join(target, suffix) if suffix
-                            else target):
-        yield

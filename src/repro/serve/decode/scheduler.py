@@ -125,6 +125,8 @@ class DecodeStats(NamedTuple):
     kv_pages_in_use: int = 0     # paged layout: pages referenced now
     kv_peak_pages: int = 0       # paged layout: high-water mark
     n_shed_kv_oom: int = 0       # sessions shed: paged arena exhausted
+    join_wait_s_total: float = 0.0   # sum of submit -> start of admission
+    n_joined: int = 0            # sessions joined to a slot
 
 
 class _Inflight(NamedTuple):
@@ -217,6 +219,8 @@ class DecodeScheduler:
         self._n_steps = 0
         self._n_prefill_skipped = 0
         self._occupancy_sum = 0.0
+        self._join_wait_s = 0.0
+        self._n_joined = 0
         # bounded token-latency telemetry (was: unbounded TTFT/ITL lists)
         self.obs = obs.MetricsRegistry(scope_prefix="decode")
         self._h_ttft = self.obs.histogram(
@@ -289,19 +293,18 @@ class DecodeScheduler:
             # tick() continuously, and idle polls are not work
             busy = (self._inflight is not None or self.pool.n_active > 0
                     or bool(self._pending))
-            span = obs.start_span("tick") if busy else None
-            self._admit()
-            prev, self._inflight = self._inflight, self._dispatch()
-            if prev is not None:
-                self._collect(prev)
-            if self._epoch is not None and self.idle:
-                # generation drained: release the pinned index epoch so
-                # a superseded index can be dropped (the next admit pins
-                # whatever epoch is serving then)
-                e, self._epoch = self._epoch, None
-                self.engine.unpin_epoch(e)
-            if span is not None:
-                span.end("ok", dispatched=self._inflight is not None,
+            with obs.span("decode.tick") if busy else obs.NOOP_SCOPE as span:
+                self._admit()
+                prev, self._inflight = self._inflight, self._dispatch()
+                if prev is not None:
+                    self._collect(prev)
+                if self._epoch is not None and self.idle:
+                    # generation drained: release the pinned index epoch
+                    # so a superseded index can be dropped (the next
+                    # admit pins whatever epoch is serving then)
+                    e, self._epoch = self._epoch, None
+                    self.engine.unpin_epoch(e)
+                span.set(dispatched=self._inflight is not None,
                          collected=prev is not None,
                          active=self.pool.n_active)
             return prev is not None or self._inflight is not None \
@@ -353,28 +356,31 @@ class DecodeScheduler:
                 # consistent with every row's prefill ranking
                 self._epoch = self.engine.pin_epoch()
             slot = self.pool.alloc()
-            pspan = obs.start_span("prefill", sid=sess.sid, slot=slot,
-                                   plen=int(sess.prompt.shape[0]))
-            try:
-                tok0 = self._prefill(slot, sess.prompt)
-            except KVPoolExhaustedError as exc:
-                # the join could not get pages (it unwound cleanly):
-                # shed this one session, keep admitting/ticking the rest
-                pspan.end_from_exc(exc)
-                obs.event("shed_kv_oom", sid=sess.sid, at="join")
-                self.pool.free(slot)
-                sess.finished = True
-                sess.stream.fail(exc)
-                self._done(sess, "shed_kv_oom")
-                continue
-            pspan.end("ok")
-            if sess.stream.span is not None:
-                sess.stream.span.event("join", slot=slot)
-            self.tok = _set_tok(self.tok, jnp.int32(slot),
-                                jnp.int32(tok0))
-            sess.slot = slot
-            self.sessions[slot] = sess
-            self._emit(sess, tok0, time.perf_counter())
+            with obs.span("decode.admit", sid=sess.sid, slot=slot,
+                          plen=int(sess.prompt.shape[0])) as aspan:
+                try:
+                    tok0 = self._prefill(slot, sess.prompt)
+                except KVPoolExhaustedError as exc:
+                    # the join could not get pages (it unwound cleanly):
+                    # shed this one session, keep admitting/ticking the
+                    # rest
+                    aspan.end_from_exc(exc)
+                    obs.event("shed_kv_oom", sid=sess.sid, at="join")
+                    self.pool.free(slot)
+                    sess.finished = True
+                    sess.stream.fail(exc)
+                    self._done(sess, "shed_kv_oom")
+                    continue
+                with self._lock:
+                    self._join_wait_s += now - sess.stream.t_submit
+                    self._n_joined += 1
+                if sess.stream.span is not None:
+                    sess.stream.span.event("join", slot=slot)
+                self.tok = _set_tok(self.tok, jnp.int32(slot),
+                                    jnp.int32(tok0))
+                sess.slot = slot
+                self.sessions[slot] = sess
+                self._emit(sess, tok0, time.perf_counter())
 
     def _prefill(self, slot: int, prompt_np: np.ndarray) -> int:
         """Fill ``slot``'s KV for a prompt and return its first token.
@@ -593,6 +599,8 @@ class DecodeScheduler:
             self._n_steps = 0
             self._n_prefill_skipped = 0
             self._occupancy_sum = 0.0
+            self._join_wait_s = 0.0
+            self._n_joined = 0
             self._h_ttft.reset()
             self._h_itl.reset()
             self._t_first = None
@@ -638,4 +646,6 @@ class DecodeScheduler:
                 kv_pages_in_use=self.pool.pages_in_use,
                 kv_peak_pages=self.pool.peak_pages_in_use,
                 n_shed_kv_oom=self._n_shed_kv_oom,
+                join_wait_s_total=self._join_wait_s,
+                n_joined=self._n_joined,
             )

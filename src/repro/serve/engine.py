@@ -110,6 +110,13 @@ def _as_label_row(labels) -> np.ndarray | None:
     return arr
 
 
+def _name_step(fn: Callable, what: str, kind: str) -> None:
+    """Name a step function ``<what>_<head>`` before it is jitted, so its
+    XLA module (``jit_score_step_lss``, ``jit_decode_step_full``) tells a
+    device trace which program ran."""
+    fn.__name__ = fn.__qualname__ = f"{what}_{kind.replace('-', '_')}"
+
+
 class Engine:
     """Batched WOL serving with a pluggable head.
 
@@ -520,6 +527,8 @@ class Engine:
                     q = embed(x) if embed is not None else x
                     return head.with_operands(q, *ops)
 
+                _name_step(raw_step, "score_step", kind)
+
                 # the head's arrays ride as jit arguments, never as
                 # closure constants (see serve.heads)
                 def step(x, _j=jax.jit(raw_step), _ops=head.operands):
@@ -589,6 +598,8 @@ class Engine:
                                             *ops)
                     tok_next = jnp.maximum(ho.ids[:, 0], 0).astype(jnp.int32)
                     return tok_next, ho, k_new, v_new
+
+                _name_step(raw_step, "decode_step", kind)
 
                 donate = ((2, 3) if jax.default_backend() == "tpu"
                           and not fleet else ())

@@ -63,7 +63,7 @@ import jax
 import numpy as np
 
 from repro import obs
-from repro.kernels.registry import dispatch_log
+from repro.kernels.registry import dispatch_count, dispatch_log
 from repro.serve.batcher import MicroBatcher
 from repro.serve.engine import Engine, RankResult
 from repro.serve.runtime.future import (DeadlineExceededError, QueueFullError,
@@ -125,6 +125,11 @@ class RuntimeStats(NamedTuple):
     prefix_hit_rate: float = math.nan   # shared / shareable prompt pages
     kv_pages_in_use: int = 0        # paged KV layout: live pages
     kv_peak_pages: int = 0          # paged KV layout: high-water mark
+    # ------------------------------------------------------ queue wait --
+    queue_wait_s_total: float = 0.0  # sum of submit -> dispatch, scoring
+    n_dispatched: int = 0            # scoring requests dispatched
+    join_wait_s_total: float = 0.0   # sum of submit -> admission, decode
+    n_joined: int = 0                # decode sessions joined to a slot
 
 
 def _paced_submit(n: int, qps: float, seed: int, submit
@@ -263,6 +268,8 @@ class AsyncRuntime:
         self._n_decode_shed_deadline = 0
         self._n_batches = 0
         self._occupancy_sum = 0.0
+        self._queue_wait_s = 0.0
+        self._n_dispatched = 0
         # bounded telemetry (was: unbounded list[float] + np.percentile
         # over full history per stats() call) — O(1) memory under any load
         self.obs = obs.MetricsRegistry(scope_prefix="runtime")
@@ -525,47 +532,56 @@ class AsyncRuntime:
                 live = self._shed_late(works)
                 if not live:
                     continue
-                span = obs.start_span("chunk", head=self.head,
-                                      n=len(live))
-                try:
-                    # host side: stack rows and pad to the bucket in
-                    # numpy — this is the work that overlaps the device
-                    # executing the PREVIOUS chunk (whose dispatch below
-                    # did not block).
-                    bucket = batcher.bucket_for(len(live))
-                    span.set(bucket=bucket)
-                    for w in live:
-                        w.future.span.event("dispatch", bucket=bucket)
-                    x = jax.tree.map(lambda *rows: np.stack(rows),
-                                     *[w.x for w in live])
-                    padded = MicroBatcher.pad_rows(x, bucket)
-                    # the engine's step seam: on a multi-process engine
-                    # (Engine(spmd=...)) this is the leader-side wrapper
-                    # that broadcasts the chunk to every follower_loop
-                    # first — the runtime needs no multihost awareness
-                    step = self.engine._step(self.head, bucket)
-                    n_disp = len(dispatch_log())
-                    n_comp = sum(self.engine.compile_counts.values())
-                    t0 = time.perf_counter()
-                    out = step(padded)          # async dispatch, no block
-                    # kernel attribution: which registry impls this chunk
-                    # dispatched, and whether it paid a (head, bucket)
-                    # compile (both non-empty only on first trace)
-                    new = dispatch_log()[n_disp:]
-                    d_comp = (sum(self.engine.compile_counts.values())
-                              - n_comp)
-                    if new or d_comp:
-                        span.set(dispatches=[f"{op}:{impl}"
-                                             for op, impl in new],
-                                 compile_delta=d_comp)
-                except Exception as e:
-                    # chunk-local failure (malformed request, trace
-                    # error): fail THIS chunk's futures, keep serving —
-                    # one bad request must not take down the front-end
-                    span.end_from_exc(e)
-                    for w in live:
-                        self._fail(w.future, e)
-                    continue
+                with obs.span("runtime.dispatch", n=len(live)):
+                    t_disp = time.perf_counter()
+                    wait = sum(t_disp - w.future.t_submit for w in live)
+                    with self._mu:
+                        self._queue_wait_s += wait
+                        self._n_dispatched += len(live)
+                    span = obs.start_span("chunk", head=self.head,
+                                          n=len(live))
+                    try:
+                        # host side: stack rows and pad to the bucket in
+                        # numpy — this is the work that overlaps the
+                        # device executing the PREVIOUS chunk (whose
+                        # dispatch below did not block).
+                        bucket = batcher.bucket_for(len(live))
+                        span.set(bucket=bucket)
+                        for w in live:
+                            w.future.span.event("dispatch", bucket=bucket)
+                        x = jax.tree.map(lambda *rows: np.stack(rows),
+                                         *[w.x for w in live])
+                        padded = MicroBatcher.pad_rows(x, bucket)
+                        # the engine's step seam: on a multi-process
+                        # engine (Engine(spmd=...)) this is the
+                        # leader-side wrapper that broadcasts the chunk to
+                        # every follower_loop first — the runtime needs
+                        # no multihost awareness
+                        step = self.engine._step(self.head, bucket)
+                        n_disp = dispatch_count()
+                        n_comp = sum(self.engine.compile_counts.values())
+                        t0 = time.perf_counter()
+                        out = step(padded)          # async dispatch
+                        # kernel attribution: which registry impls this
+                        # chunk dispatched, and whether it paid a (head,
+                        # bucket) compile (both non-empty only on first
+                        # trace)
+                        new = dispatch_log(n_disp)
+                        d_comp = (sum(self.engine.compile_counts.values())
+                                  - n_comp)
+                        if new or d_comp:
+                            span.set(dispatches=[f"{op}:{impl}"
+                                                 for op, impl in new],
+                                     compile_delta=d_comp)
+                    except Exception as e:
+                        # chunk-local failure (malformed request, trace
+                        # error): fail THIS chunk's futures, keep serving
+                        # — one bad request must not take down the
+                        # front-end
+                        span.end_from_exc(e)
+                        for w in live:
+                            self._fail(w.future, e)
+                        continue
                 self._put_done((live, out, bucket, t0, span))
         except BaseException as e:              # fail loudly, not silently
             self._abort(e)
@@ -594,14 +610,15 @@ class AsyncRuntime:
         """Hand a dispatched chunk to the completion thread; if the
         completion thread died, fail the chunk's futures instead of
         blocking forever (or stranding the chunk in the queue)."""
-        while self._worker_exc is None:
-            try:
-                self._done_q.put(item, timeout=0.1)
-                break
-            except _queue.Full:
-                if self._stop.is_set():
-                    self._fail_chunk(item)
-                    return
+        with obs.span("runtime.handoff"):
+            while self._worker_exc is None:
+                try:
+                    self._done_q.put(item, timeout=0.1)
+                    break
+                except _queue.Full:
+                    if self._stop.is_set():
+                        self._fail_chunk(item)
+                        return
         # _abort sets _worker_exc BEFORE draining _done_q, so if the
         # completion thread died around our put, one of the two drains
         # (abort's, or this reclaim) is guaranteed to see the chunk
@@ -637,32 +654,34 @@ class AsyncRuntime:
                 works, out, bucket, t0, span = item
                 jax.block_until_ready(out.logits)
                 t1 = time.perf_counter()
-                # chunks overlap under pipelining (chunk k+1 is dispatched
-                # while k executes), so attribute each chunk only the wall
-                # PAST the previous chunk's completion — the summed walls
-                # then add up to pipeline busy time instead of ~2x it
-                prev = self._t_last
-                wall = t1 - (t0 if prev is None else max(t0, prev))
-                n = len(works)
-                logits = np.asarray(out.logits)[:n]
-                ids = np.asarray(out.ids)[:n]
-                lats = [t1 - w.future.t_submit for w in works]
-                labels = Engine._stack_labels([w.labels for w in works])
-                self.engine._record(out, n, wall, lats, labels)
-                aud = getattr(self.engine, "auditor", None)
-                if aud is not None and self.head != "full":
-                    # thunk: the unpadded re-stack is only paid when the
-                    # auditor's coin flip samples this chunk
-                    aud.offer(lambda ws=works: jax.tree.map(
-                        lambda *rows: np.stack(rows), *[w.x for w in ws]),
-                        ids)
-                span.end("ok", device_s=wall)
-                for i, w in enumerate(works):
-                    w.future.set_result(
-                        RankResult(w.future.rid, logits[i], ids[i]))
-                for v in lats:
-                    self._h_lat.record(v)
-                self._h_device.record(wall)
+                with obs.span("runtime.record", bucket=bucket):
+                    # chunks overlap under pipelining (chunk k+1 is
+                    # dispatched while k executes), so attribute each
+                    # chunk only the wall PAST the previous chunk's
+                    # completion — the summed walls then add up to
+                    # pipeline busy time instead of ~2x it
+                    prev = self._t_last
+                    wall = t1 - (t0 if prev is None else max(t0, prev))
+                    n = len(works)
+                    logits = np.asarray(out.logits)[:n]
+                    ids = np.asarray(out.ids)[:n]
+                    lats = [t1 - w.future.t_submit for w in works]
+                    labels = Engine._stack_labels([w.labels for w in works])
+                    self.engine._record(out, n, wall, lats, labels)
+                    aud = getattr(self.engine, "auditor", None)
+                    if aud is not None and self.head != "full":
+                        # thunk: the unpadded re-stack is only paid when
+                        # the auditor's coin flip samples this chunk
+                        aud.offer(lambda ws=works: jax.tree.map(
+                            lambda *rows: np.stack(rows),
+                            *[w.x for w in ws]), ids)
+                    span.end("ok", device_s=wall)
+                    for i, w in enumerate(works):
+                        w.future.set_result(
+                            RankResult(w.future.rid, logits[i], ids[i]))
+                    for v in lats:
+                        self._h_lat.record(v)
+                    self._h_device.record(wall)
                 with self._drained:
                     self._n_completed += n
                     self._n_batches += 1
@@ -766,6 +785,8 @@ class AsyncRuntime:
                 prefix_hit_rate=ds.prefix_hit_rate,
                 kv_pages_in_use=ds.kv_pages_in_use,
                 kv_peak_pages=ds.kv_peak_pages,
+                join_wait_s_total=ds.join_wait_s_total,
+                n_joined=ds.n_joined,
             )
             return RuntimeStats(**decode,
                 n_submitted=self._n_submitted,
@@ -783,4 +804,6 @@ class AsyncRuntime:
                 wall_s=wall,
                 throughput_rps=(self._n_completed / wall if wall > 0
                                 else 0.0),
+                queue_wait_s_total=self._queue_wait_s,
+                n_dispatched=self._n_dispatched,
             )

@@ -85,6 +85,10 @@ def test_dispatch_log_records_op_and_impl():
         ("simhash_codes", "ref"), ("simhash_codes", "pallas_interpret"))
     assert registry.last_dispatch("simhash_codes") == "pallas_interpret"
     assert registry.dispatch_counts()[("simhash_codes", "ref")] == 1
+    # a mark taken before a call copies only what the call added
+    assert registry.dispatch_count() == 2
+    assert registry.dispatch_log(1) == (("simhash_codes", "pallas_interpret"),)
+    assert registry.dispatch_log(registry.dispatch_count()) == ()
 
 
 # ------------------------------------- sub-op bit-exact parity (edge d/P) --

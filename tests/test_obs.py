@@ -5,6 +5,7 @@ runtime failure path (queue shed, deadline shed, KV OOM, chunk-local
 fault, close), the no-op disabled mode, and the online recall auditor
 against an offline brute-force rerank."""
 
+import inspect
 import json
 import threading
 import time
@@ -344,6 +345,182 @@ def test_kv_oom_shed_span_and_event():
     oom_events = [e for e in obs.trace_export()["traceEvents"]
                   if e["name"] == "shed_kv_oom"]
     assert oom_events
+
+
+# ------------------------------------- scoped spans on the profiler clock --
+
+SCOPED = {"runtime.dispatch", "runtime.handoff", "runtime.record",
+          "decode.tick", "decode.admit"}
+
+
+def _tiny_lm(name, **kw):
+    cfg = T.TransformerConfig(name=name, n_layers=2, d_model=32, n_heads=2,
+                              n_kv_heads=2, head_dim=16, d_ff=64, vocab=256,
+                              dtype=jnp.float32, kv_chunk=32)
+    params = T.init_params(jax.random.PRNGKey(3), cfg)
+    return LMDecoder(params, cfg, max_streams=2, max_len=16, **kw)
+
+
+def _serve_chunk_and_session():
+    """One score chunk through an AsyncRuntime, then one decode session
+    admitted and ticked to its end by a standalone scheduler."""
+    eng = _engine()
+    with AsyncRuntime(eng) as rt:
+        rt.submit(np.zeros(32, np.float32)).result(timeout=60.0)
+    sched = _tiny_lm("tp-obs-prof").scheduler(head="full")
+    st = sched.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=2)
+    sched.run(timeout=120.0)
+    assert st.finish_reason == "max_tokens"
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under a CPU ``jax.profiler`` trace with the Python
+    tracer off, as the benchmark's traced runs record, and read the
+    trace back with the benchmark's own reader."""
+    from bench import trace
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    return trace.load_events(trace.xplane_file(str(tmp_path)))
+
+
+def test_scoped_spans_reach_the_profiler_host_plane(tmp_path):
+    _serve_chunk_and_session()                  # compile outside the trace
+    obs.reset_tracer()
+    evs = _profiled(tmp_path, _serve_chunk_and_session)
+    host = [e for e in evs if e.plane.startswith("/host:CPU")]
+    names = {e.name for e in host}
+    assert {"runtime.dispatch", "runtime.record", "decode.tick",
+            "decode.admit"} <= names
+    ticks = [e for e in host if e.name == "decode.tick"]
+    admits = [e for e in host if e.name == "decode.admit"]
+    assert len(admits) == 1
+    a = admits[0]
+    assert any(t.start <= a.start and a.start + a.dur <= t.start + t.dur
+               for t in ticks)
+    # the same spans are in the ring, with their attributes
+    ring = {e["name"]: e for e in obs.trace_export()["traceEvents"]
+            if e["ph"] == "X"}
+    assert ring["runtime.record"]["args"]["bucket"] == 8
+    assert ring["decode.admit"]["args"]["status"] == "ok"
+    assert {"tick", "prefill"}.isdisjoint(ring)
+
+
+def test_idle_dispatcher_records_no_span():
+    """An idle runtime polls its queue and ticks its scheduler every
+    50 ms; idle polls are not work, so they must not push the operator's
+    ``request`` / ``chunk`` / ``decode_session`` spans out of the ring."""
+    dec = _tiny_lm("tp-obs-idle")
+    with AsyncRuntime(dec.engine, scheduler=dec.scheduler(head="full")):
+        time.sleep(0.3)
+    assert obs.trace_export()["traceEvents"] == []
+
+
+def test_disabled_obs_leaves_no_span_in_ring_or_trace(tmp_path):
+    prev = obs.enabled()
+    obs.set_enabled(False)
+    try:
+        evs = _profiled(tmp_path, _serve_chunk_and_session)
+        assert obs.trace_export()["traceEvents"] == []
+    finally:
+        obs.set_enabled(prev)
+    assert SCOPED.isdisjoint(e.name for e in evs)
+
+
+def test_scoped_span_status_follows_the_block():
+    with pytest.raises(ValueError):
+        with obs.span("test.step", k=1):
+            raise ValueError("boom")
+    with obs.span("test.shed") as s:
+        s.end("shed_queue")                     # first terminal status wins
+    with obs.span("test.ok"):
+        pass
+    status = {e["name"]: e["args"]["status"]
+              for e in obs.trace_export()["traceEvents"] if e["ph"] == "X"}
+    assert status == {"test.step": "error", "test.shed": "shed_queue",
+                      "test.ok": "ok"}
+
+
+def test_join_oom_ends_the_admit_span_shed_kv_oom():
+    """A prompt the paged arena cannot hold fails at join: its
+    ``decode.admit`` span ends shed_kv_oom and counts no join."""
+    dec = _tiny_lm("tp-obs-joinoom", kv_layout="paged", kv_page_tokens=4,
+                   kv_pages=2)                  # scratch + 1 page
+    sched = dec.scheduler(head="full")
+    st = sched.submit(np.arange(1, 7, dtype=np.int32), max_new_tokens=2)
+    sched.run(timeout=120.0)
+    assert isinstance(st.exception(), KVPoolExhaustedError)
+    [admit] = [e for e in obs.trace_export()["traceEvents"]
+               if e["ph"] == "X" and e["name"] == "decode.admit"]
+    assert admit["args"]["status"] == "shed_kv_oom"
+    assert sched.stats().n_joined == 0
+
+
+def test_queue_and_join_waits_are_counted():
+    eng = _engine()
+    rt = AsyncRuntime(eng, start=False)
+    futs = [rt.submit(np.zeros(32, np.float32)) for _ in range(3)]
+    time.sleep(0.05)
+    rt.start()
+    for f in futs:
+        f.result(timeout=60.0)
+    rt.close()
+    s = rt.stats()
+    assert s.n_dispatched == 3
+    assert 3 * 0.05 <= s.queue_wait_s_total < 3 * 60.0
+
+    dec = _tiny_lm("tp-obs-joinwait")
+    sched = dec.scheduler(head="full")
+    rt = AsyncRuntime(dec.engine, scheduler=sched, start=False)
+    st = rt.submit_decode(np.arange(1, 6, dtype=np.int32), max_new_tokens=2)
+    time.sleep(0.05)
+    rt.start()
+    rt.drain(timeout=120.0)
+    rt.close()
+    assert st.finish_reason == "max_tokens"
+    s = rt.stats()
+    assert s.n_joined == 1 and s.n_dispatched == 0
+    assert 0.05 <= s.join_wait_s_total < 120.0
+    sched.reset_stats()
+    ds = sched.stats()
+    assert ds.n_joined == 0 and ds.join_wait_s_total == 0.0
+
+
+def _jitted(step):
+    """The jitted program and head operands a step closure calls."""
+    params = inspect.signature(step).parameters
+    return params["_j"].default, params["_ops"].default
+
+
+@pytest.mark.parametrize("kind", ["full", "lss"])
+def test_jitted_steps_lower_to_named_modules(kind):
+    eng = _engine()
+    j, ops = _jitted(eng._step(kind, 8))
+    assert (f"module @jit_score_step_{kind} "
+            in j.lower(jnp.zeros((8, 32)), *ops).as_text())
+    dec = _tiny_lm(f"tp-obs-name-{kind}")
+    if kind != "full":
+        dec.engine.fit_random(jax.random.PRNGKey(1))
+    sched = dec.scheduler(head=kind)
+    j, ops = _jitted(dec.engine.decode_logits(kind, sched._tag, sched._body))
+    text = j.lower(sched.params, sched.tok, *sched.pool.step_operands(),
+                   *ops).as_text()
+    assert f"module @jit_decode_step_{kind} " in text
+    assert "raw_step" not in text
+
+
+def test_step_names_replace_the_head_kinds_dash():
+    from repro.serve.engine import _name_step
+
+    def f(x):
+        return x
+    _name_step(f, "decode_step", "lss-sharded")
+    assert "module @jit_decode_step_lss_sharded " in \
+        jax.jit(f).lower(jnp.zeros(2)).as_text()
 
 
 # --------------------------------------------------------- recall audit --

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from repro.core import simhash
 from repro.core.lss import (LSSConfig, LSSIndex, build_index, retrieve,
                             sparse_logits_gather, label_recall)
+from repro.kernels.registry import resolve_impl
 from repro.optim import adamw_init, adamw_update
 
 __all__ = ["MinedPairs", "mine_pairs", "calibrate_thresholds", "iul_loss",
@@ -157,9 +158,17 @@ def iul_train_epoch(theta, opt_state, q_aug_all, labels_all, w_aug, index,
 # Module-level jitted programs shared by the offline fit AND the online
 # refresher: jax.jit caches per function object, so per-call jax.jit
 # wrappers would retrace every refresh cycle.  ``cfg`` (a hashable
-# NamedTuple) is the static argument.
+# NamedTuple) is the static argument; so is the rebuild's ``impl``,
+# resolved before the call so the cached program's slab layout cannot
+# go stale when the process default or the environment changes.
 _EPOCH_JIT = jax.jit(iul_train_epoch, static_argnames=("cfg",))
-_REBUILD_JIT = jax.jit(build_index, static_argnames=("cfg",))
+_REBUILD_JIT = jax.jit(build_index, static_argnames=("cfg", "impl"))
+
+
+def _rebuild(w_aug: jax.Array, theta: jax.Array, cfg: LSSConfig,
+             impl: str | None) -> LSSIndex:
+    return _REBUILD_JIT(w_aug, theta, cfg,
+                        impl=resolve_impl("lss_topk", impl))
 
 
 class IULState(NamedTuple):
@@ -197,18 +206,20 @@ def iul_init(key, q_aug: jax.Array, labels_all: jax.Array,
 
 def iul_refit_epoch(state: IULState, q_aug: jax.Array,
                     labels_all: jax.Array, w_aug: jax.Array,
-                    index: LSSIndex, cfg: LSSConfig
+                    index: LSSIndex, cfg: LSSConfig,
+                    impl: str | None = None
                     ) -> tuple[IULState, LSSIndex, dict]:
     """ONE training epoch + rebuild against a frozen snapshot — the
     online refresher's unit of work (pure jax, no engine state, safe
     entirely off the serving hot path).  Mines against ``index`` (the
     previous rebuild, per Algorithm 1), returns the advanced state, the
-    candidate index, and the epoch's metrics."""
+    candidate index (in the slab layout ``impl`` serves, see
+    ``core.lss.build_index``), and the epoch's metrics."""
     key, ke = jax.random.split(state.key)
     theta, opt_state, (loss, cp, cn) = _EPOCH_JIT(
         state.theta, state.opt_state, q_aug, labels_all, w_aug, index,
         state.t1, state.t2, cfg, ke)
-    new_index = _REBUILD_JIT(w_aug, theta, cfg)
+    new_index = _rebuild(w_aug, theta, cfg, impl)
     info = {"loss": float(loss.mean()),
             "p_collide_pos": float(cp.mean()),
             "p_collide_neg": float(cn.mean()),
@@ -227,10 +238,12 @@ def calib_recall(index: LSSIndex, q_aug: jax.Array, labels_all: jax.Array,
 
 def fit_lss(key, q_all: jax.Array, labels_all: jax.Array, w: jax.Array,
             b: jax.Array | None, cfg: LSSConfig,
-            verbose: bool = False):
+            verbose: bool = False, impl: str | None = None):
     """Full offline preprocessing (paper Algorithm 1, iterated).
 
-    Returns (index, history dict of per-epoch metrics).
+    ``impl`` is the ``lss_topk`` impl that will serve the index, which
+    picks its slab layout.  Returns (index, history dict of per-epoch
+    metrics).
     """
     w_aug = simhash.augment_neurons(w, b)
     q_aug = simhash.augment_queries(q_all)
@@ -243,11 +256,11 @@ def fit_lss(key, q_all: jax.Array, labels_all: jax.Array, w: jax.Array,
     # the weight slabs in a single XLA program instead of re-dispatching
     # the whole op chain eagerly per epoch — the dominant fit_lss cost at
     # m >= 1M on CPU.
-    index = _REBUILD_JIT(w_aug, state.theta, cfg)
+    index = _rebuild(w_aug, state.theta, cfg, impl)
     best_index, best_rec = index, -1.0
     for ep in range(cfg.iul_epochs):
         state, index, info = iul_refit_epoch(state, q_aug, labels_all,
-                                             w_aug, index, cfg)
+                                             w_aug, index, cfg, impl)
         rec = info["recall"]
         # model selection: IUL's mining distribution shifts every rebuild,
         # so individual epochs can regress — serve the best epoch's index

@@ -32,10 +32,18 @@ table in ``LSSIndex.w_scale``) and both lss_topk impls dequantize on
 the fly.  Because ``fit_lss`` rebuilds the index through this same
 constructor every IUL epoch, refits REQUANTIZE automatically — there is
 no path that silently mixes fp32 tables with stale quantized slabs.
+
+The slab LAYOUT is decided at build time the same way: storage follows
+the impl that will serve it (``build_index(..., impl=)``, resolved like
+the ``lss_topk`` op's impl).  ``pallas`` gets the TPU kernel's aligned
+layout (``kernels.lss_topk.slabs.kernel_slabs``), stored INSTEAD of the
+logical ``[L, 2^K, P, d]`` tensor, so no call re-lays the index out;
+``ref`` and ``pallas_interpret`` keep the logical layout.
 """
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple
 
 import jax
@@ -44,8 +52,9 @@ import jax.numpy as jnp
 from repro.core import simhash
 from repro.core.tables import LSSTables, build_tables, bucketize_weights
 from repro.kernels import bucket_logits, lss_topk, simhash_codes
-from repro.kernels.lss_topk.slabs import (dequantize_slabs, quantize_slabs,
-                                          resolve_slab_dtype)
+from repro.kernels.lss_topk.slabs import (dequantize_slabs, kernel_slabs,
+                                          logical_slabs, quantize_slabs,
+                                          resolve_slab_dtype, slab_layout_for)
 
 __all__ = [
     "LSSConfig", "LSSIndex", "build_index", "retrieve", "dedup_mask",
@@ -88,36 +97,74 @@ class LSSIndex(NamedTuple):
     Hash tables are always built from the fp32 ``w_aug``, so candidate
     retrieval (the paper's label recall) is identical across formats;
     only the ranked logits see quantization error.
+
+    In the aligned layout (an index built for the ``pallas`` impl) the
+    slabs are ``[L*2^K, P', d']``, the scales ``[L*2^K, 1, P']`` and
+    ``slab_ids`` holds the matching ``[L*2^K, 1, P']`` ids; ``tables``
+    stays logical (``capacity`` is P) in both layouts.
     """
 
     theta: jax.Array             # [d_aug, K*L] learned hyperplanes
     tables: LSSTables            # bucket-major neuron ids
     w_bucketed: jax.Array | None  # [L, 2^K, P, d_aug] or None (gather path)
     w_scale: jax.Array | None = None  # [L, 2^K, P] f32, int8 storage only
+    slab_ids: jax.Array | None = None  # [L*2^K, 1, P'] i32, aligned only
 
 
 jax.tree_util.register_pytree_node(
     LSSIndex,
-    lambda i: ((i.theta, i.tables, i.w_bucketed, i.w_scale), None),
+    lambda i: ((i.theta, i.tables, i.w_bucketed, i.w_scale, i.slab_ids),
+               None),
     lambda _, leaves: LSSIndex(*leaves),
 )
 
 
-def build_index(w_aug: jax.Array, theta: jax.Array, cfg: LSSConfig) -> LSSIndex:
+def _aligned_slabs(w_aug: jax.Array, tables: LSSTables, slab_dtype: str
+                   ) -> tuple[jax.Array, jax.Array, jax.Array | None]:
+    """Bucketize, quantize and lay out a few slabs at a time, straight
+    into the aligned tensor: the logical tensor is never whole, so the
+    build holds little beyond what it returns."""
+    n_slabs = tables.n_tables * tables.n_buckets
+    chunk = math.gcd(n_slabs, 64)
+
+    def some_slabs(ids: jax.Array):                      # [chunk, P]
+        part = tables._replace(table_ids=ids[None])
+        wb, w_scale = quantize_slabs(bucketize_weights(w_aug, part),
+                                     slab_dtype)
+        return kernel_slabs(part.table_ids, wb, w_scale)
+
+    out = jax.lax.map(some_slabs, tables.table_ids.reshape(
+        n_slabs // chunk, chunk, tables.capacity))
+    return jax.tree.map(lambda x: x.reshape(n_slabs, *x.shape[2:]), out)
+
+
+_ALIGNED_SLABS_JIT = jax.jit(_aligned_slabs, static_argnames=("slab_dtype",))
+
+
+def build_index(w_aug: jax.Array, theta: jax.Array, cfg: LSSConfig, *,
+                impl: str | None = None) -> LSSIndex:
     """(Re)build tables (and slabs) for the current hyperplanes.
 
     Resolves the slab storage format (``cfg.slab_dtype`` >
     ``lss_topk.slab_dtype`` strategy) and quantizes the bucket-major
     slabs at construction, so every rebuild — including each IUL refit
     epoch inside ``fit_lss``'s jitted ``rebuild`` — requantizes from the
-    current fp32 weights.
+    current fp32 weights.  ``impl`` is the ``lss_topk`` impl that will
+    serve the index (None = the registry's resolution): ``pallas`` gets
+    the aligned slab layout, the others the logical one.  The tables
+    are built at the logical capacity either way, so both layouts hold
+    the same neurons and drop the same overflow.
     """
     cap = cfg.resolve_capacity(w_aug.shape[0])
     tables = build_tables(w_aug, theta, cfg.k_bits, cfg.n_tables, cap)
     if not cfg.use_bucket_major:
         return LSSIndex(theta, tables, None, None)
-    wb, w_scale = quantize_slabs(bucketize_weights(w_aug, tables),
-                                 resolve_slab_dtype(cfg.slab_dtype))
+    slab_dtype = resolve_slab_dtype(cfg.slab_dtype)
+    if slab_layout_for(impl) == "aligned":
+        ids, wb, w_scale = _ALIGNED_SLABS_JIT(w_aug, tables,
+                                              slab_dtype=slab_dtype)
+        return LSSIndex(theta, tables, wb, w_scale, ids)
+    wb, w_scale = quantize_slabs(bucketize_weights(w_aug, tables), slab_dtype)
     return LSSIndex(theta, tables, wb, w_scale)
 
 
@@ -181,12 +228,15 @@ def sparse_logits_bucketed(q_aug: jax.Array, index: LSSIndex,
 
     Routes through the registry ``bucket_logits`` op on the flattened
     ``[S, P, d]`` slab layout (S = L * 2^K) — the jnp ref for the XLA
-    path, the scalar-prefetch Pallas kernel on TPU.
+    path, the scalar-prefetch Pallas kernel on TPU.  Aligned storage is
+    sliced back to the logical layout first.
     """
     t = index.tables
     # this unfused path hands whole slabs to bucket_logits, so widen
     # quantized storage up front (the fused lss_topk path widens in-kernel)
-    wb = dequantize_slabs(index.w_bucketed, index.w_scale)  # [L, 2^K, P, d]
+    wb = dequantize_slabs(*logical_slabs(
+        index.w_bucketed, index.w_scale, t.table_ids.shape,
+        q_aug.shape[1]))                                  # [L, 2^K, P, d]
     w_flat = wb.reshape(t.n_tables * t.n_buckets, t.capacity, wb.shape[-1])
     slab_ids = buckets + jnp.arange(
         t.n_tables, dtype=buckets.dtype)[None, :] * t.n_buckets   # [B, L]
@@ -227,7 +277,7 @@ def lss_forward(q: jax.Array, index: LSSIndex, w_aug: jax.Array | None,
         t = index.tables
         out = lss_topk(q_aug, index.theta, t.table_ids, index.w_bucketed,
                        top_k=top_k, impl=impl, dedup=dedup,
-                       w_scale=index.w_scale)
+                       w_scale=index.w_scale, slab_ids=index.slab_ids)
         return LSSForward(*out)
     cand_ids, _ = retrieve(q_aug, index, impl=impl)
     logits = sparse_logits_gather(q_aug, w_aug, cand_ids)

@@ -36,10 +36,12 @@ __all__ = ["build_local_index", "local_topk", "sharded_lss_predict",
 
 
 def build_local_index(w_aug_local: jax.Array, theta: jax.Array,
-                      cfg: LSSConfig) -> LSSIndex:
+                      cfg: LSSConfig, *, impl: str | None = None
+                      ) -> LSSIndex:
     """Build the index for this shard's rows (call inside shard_map or on
-    pre-split host arrays). Neuron ids inside are LOCAL row indices."""
-    return build_index(w_aug_local, theta, cfg)
+    pre-split host arrays). Neuron ids inside are LOCAL row indices;
+    ``impl`` picks the slab layout (``core.lss.build_index``)."""
+    return build_index(w_aug_local, theta, cfg, impl=impl)
 
 
 def local_topk(q: jax.Array, index: LSSIndex, w_aug_local: jax.Array | None,

@@ -24,7 +24,9 @@ serve.heads, the engine's jitted steps) leans on them:
   format ``w_bucketed`` arrives in and requires ``w_scale`` iff it is
   int8.  DMA/VMEM cost helpers (``lss_topk_vmem_bytes``,
   ``lss_topk_slab_dma_bytes``) take the format so capacity planning
-  reflects the real byte traffic.
+  reflects the real byte traffic.  The slab layout is chosen at build
+  too, by the impl that will serve: aligned (the kernel's own operands)
+  for ``pallas``, logical for the others (``slabs.slab_layout_for``).
 """
 
 from repro.kernels.lss_topk.dedup import (dedup_auto_threshold,

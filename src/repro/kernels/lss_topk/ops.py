@@ -20,7 +20,10 @@ see ``kernels.lss_topk.slabs``), is resolved at INDEX BUILD time rather
 than per call: this op simply consumes whatever storage format
 ``w_bucketed`` arrives in, taking the per-neuron-row scale table via
 ``w_scale`` when the slabs are int8 and dequantizing on the fly inside
-each impl.
+each impl.  The slab layout is the build's choice too: the ``pallas``
+impl reads aligned storage as stored and lays out logical storage in
+the call, and records which in the registry dispatch log as
+``("lss_topk.slab_layout", "stored" | "padded_per_call")``.
 
 There is no hardcoded candidate ceiling anymore: past the old ~2k
 comfort limit the strategy auto-switches to the bitonic dedup, and a
@@ -38,6 +41,7 @@ import warnings
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import registry
 from repro.kernels.lss_topk import dedup as dedup_mod
 from repro.kernels.lss_topk import slabs as slabs_mod
 from repro.kernels.lss_topk.kernel import DEFAULT_BLOCK_Q, lss_topk_pallas
@@ -145,7 +149,8 @@ def _check_vmem(n_candidates: int, d: int, cap: int, block_q: int,
 def _pallas_impl(q_aug: jax.Array, theta: jax.Array, table_ids: jax.Array,
                  w_bucketed: jax.Array, *, top_k: int, interpret: bool,
                  dedup: str | None = None, block_q: int | None = None,
-                 w_scale: jax.Array | None = None
+                 w_scale: jax.Array | None = None,
+                 slab_ids: jax.Array | None = None
                  ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     n_tables, n_buckets, cap = table_ids.shape
     k_bits = n_buckets.bit_length() - 1
@@ -156,39 +161,32 @@ def _pallas_impl(q_aug: jax.Array, theta: jax.Array, table_ids: jax.Array,
     choice = (dedup if dedup is not None
               else dedup_mod.resolve_dedup(None, n_candidates=n_tables * cap))
     bq = effective_block_q(bsz, block_q)
-    tids = table_ids.reshape(n_tables * n_buckets, 1, cap)
-    w_flat = w_bucketed.reshape(n_tables * n_buckets, cap, d)
-    scales = (w_scale.reshape(n_tables * n_buckets, 1, cap)
-              .astype(jnp.float32) if w_scale is not None else None)
+    stored = slabs_mod.is_aligned(w_bucketed, d)
+    registry.record("lss_topk.slab_layout",
+                    "stored" if stored else "padded_per_call")
+    if stored:
+        # built for this kernel (core.lss.build_index): pass it straight
+        tids, w_flat, scales = slab_ids, w_bucketed, w_scale
+    else:
+        # logical storage: lay it out here, in every call.  TPU pads to
+        # lane multiples; interpret mode runs unpadded so the fp32
+        # reductions are bit-identical to the jnp oracle (see kernel.py).
+        tids, w_flat, scales = slabs_mod.kernel_slabs(
+            table_ids, w_bucketed, w_scale,
+            lane=None if interpret else slabs_mod.SLAB_LANE)
     # Query-tile padding applies in BOTH modes (the grid is blocked
     # either way): zero rows hash to some bucket like any query, produce
     # ordinary per-row outputs, and are sliced off below — padding can
     # never reach a real query's top-k because every row's dedup + top-k
-    # is row-local.
+    # is row-local.  Query and theta columns pad to the slabs' width.
+    cap_k, d_k = w_flat.shape[1:]        # the slab the kernel sees
     pad_b = (-bsz) % bq
-    if pad_b:
-        q_aug = jnp.pad(q_aug, ((0, pad_b), (0, 0)))
-    pad_p = 0
-    if not interpret:
-        # TPU lane alignment; interpret mode runs unpadded so the fp32
-        # reductions are bit-identical to the jnp oracle (see kernel.py).
-        pad_d = (-d) % 128
-        pad_p = (-cap) % 128
-        if pad_d:
-            q_aug = jnp.pad(q_aug, ((0, 0), (0, pad_d)))
-            theta = jnp.pad(theta, ((0, pad_d), (0, 0)))
-            w_flat = jnp.pad(w_flat, ((0, 0), (0, 0), (0, pad_d)))
-        if pad_p:
-            w_flat = jnp.pad(w_flat, ((0, 0), (0, pad_p), (0, 0)))
-            # padded capacity slots must read as empty, not as neuron 0
-            tids = jnp.pad(tids, ((0, 0), (0, 0), (0, pad_p)),
-                           constant_values=-1)
-            if scales is not None:
-                # padded slots hold zero codes; 0 * 0.0 dequantizes to 0
-                scales = jnp.pad(scales, ((0, 0), (0, 0), (0, pad_p)))
-    cap_k = cap + pad_p                  # the slab height the kernel sees
+    if pad_b or d_k > d:
+        q_aug = jnp.pad(q_aug, ((0, pad_b), (0, d_k - d)))
+    if d_k > d:
+        theta = jnp.pad(theta, ((0, d_k - d), (0, 0)))
     est = lss_topk_vmem_bytes(
-        n_tables * cap_k, q_aug.shape[1], cap_k, block_q=bq, dedup=choice,
+        n_tables * cap_k, d_k, cap_k, block_q=bq, dedup=choice,
         kl=theta.shape[1], slab_dtype=slabs_mod.slab_dtype_of(w_flat))
     top_logits, top_ids, sample, cand = lss_topk_pallas(
         q_aug, theta, tids, w_flat, scales, k_bits=k_bits,
@@ -199,8 +197,8 @@ def _pallas_impl(q_aug: jax.Array, theta: jax.Array, table_ids: jax.Array,
         top_ids = top_ids[:bsz]
         sample = sample[:bsz]
         cand = cand[:bsz]
-    if pad_p:
-        cand = cand.reshape(bsz, n_tables, -1)[:, :, :cap]
+    if cap_k > cap:
+        cand = cand.reshape(bsz, n_tables, cap_k)[:, :, :cap]
         cand = cand.reshape(bsz, n_tables * cap)
     return top_logits, top_ids, sample[:, 0], cand
 
@@ -213,12 +211,19 @@ lss_topk_op.register_impl(
 
 def lss_topk(q_aug: jax.Array, theta: jax.Array, table_ids: jax.Array,
              w_bucketed: jax.Array, *, top_k: int, impl: str | None = None,
-             dedup: str | None = None, w_scale: jax.Array | None = None
+             dedup: str | None = None, w_scale: jax.Array | None = None,
+             slab_ids: jax.Array | None = None
              ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Fused Algorithm-2 forward over a bucket-major index.
 
     ``[B,d] x [d,KL] x [L,2^K,P] x [L,2^K,P,d] ->``
     ``(top_logits [B,k], top_ids [B,k], sample_size [B], cand_ids [B,L*P])``
+
+    ``w_bucketed`` (with ``w_scale``) may instead be stored in the TPU
+    kernel's aligned layout ``[L*2^K, P', d']`` with its ids in
+    ``slab_ids`` ``[L*2^K, 1, P']`` (``kernels.lss_topk.slabs``); the
+    ``pallas`` impl then reads it as stored, the others slice it back.
+    ``table_ids`` stays the logical table either way.
 
     impl:    ``ref`` | ``pallas`` | ``pallas_interpret`` | None (registry
              auto-selection — see ``repro.kernels.registry``).
@@ -228,6 +233,7 @@ def lss_topk(q_aug: jax.Array, theta: jax.Array, table_ids: jax.Array,
              iff ``w_bucketed`` stores int8 slabs (the
              ``lss_topk.slab_dtype`` knob is resolved at index build
              time; see ``repro.kernels.lss_topk.slabs``).
+    slab_ids: the aligned layout's ids; None for logical storage.
     """
     n_tables, _, capacity = table_ids.shape
     c = n_tables * capacity
@@ -242,4 +248,5 @@ def lss_topk(q_aug: jax.Array, theta: jax.Array, table_ids: jax.Array,
     _check_vmem(c, q_aug.shape[1], capacity, bq, choice, theta.shape[1],
                 sdt)
     return lss_topk_op(q_aug, theta, table_ids, w_bucketed, top_k=top_k,
-                       dedup=choice, w_scale=w_scale, impl=impl)
+                       dedup=choice, w_scale=w_scale, slab_ids=slab_ids,
+                       impl=impl)

@@ -32,14 +32,15 @@ from repro.kernels.bucket_logits.ref import bucket_logits_ref
 from repro.kernels.lss_topk.dedup import (dedup_mask_bitonic,
                                           dedup_mask_quadratic,
                                           resolve_dedup)
-from repro.kernels.lss_topk.slabs import dequantize_slabs
+from repro.kernels.lss_topk.slabs import dequantize_slabs, logical_slabs
 from repro.kernels.simhash_codes.ref import simhash_codes_ref
 
 
 def lss_topk_ref(q_aug: jax.Array, theta: jax.Array, table_ids: jax.Array,
                  w_bucketed: jax.Array, *, top_k: int,
                  dedup: str | None = None,
-                 w_scale: jax.Array | None = None
+                 w_scale: jax.Array | None = None,
+                 slab_ids: jax.Array | None = None
                  ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Retrieve -> slab logits -> dedup mask -> top-k, all in jnp.
 
@@ -54,6 +55,8 @@ def lss_topk_ref(q_aug: jax.Array, theta: jax.Array, table_ids: jax.Array,
                   auto-select on C = L*P).
       w_scale:    fp32 ``[L, 2^K, P]`` per-neuron-row scales (int8
                   storage only, else None).
+      slab_ids:   unused: slabs stored in the TPU kernel's aligned
+                  layout are sliced back to the logical one here.
 
     Returns:
       (top_logits [B,k] f32, top_ids [B,k] i32, sample_size [B] i32,
@@ -70,7 +73,8 @@ def lss_topk_ref(q_aug: jax.Array, theta: jax.Array, table_ids: jax.Array,
     bsz = q_aug.shape[0]
     # dequantize-on-the-fly, oracle form: widen once, elementwise — the
     # kernel widens per fetched slab, which is the same values
-    w_bucketed = dequantize_slabs(w_bucketed, w_scale)
+    w_bucketed = dequantize_slabs(*logical_slabs(
+        w_bucketed, w_scale, table_ids.shape, q_aug.shape[1]))
 
     # sign(theta^T x) is scale-invariant; normalizing first matches the
     # hash definition in core.simhash (shared with the IUL relaxation).

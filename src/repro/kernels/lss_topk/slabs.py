@@ -41,6 +41,16 @@ an elementwise fp32 op (``q * scale``), both paths feed bit-identical
 operand matrices to the same row-consistent gemm, so the ref /
 pallas-interpret exact-equality contract of the fp32 path carries over
 unchanged to every storage format (tested in ``tests/test_slab_quant.py``).
+
+The slab LAYOUT is decided at build time too, by the impl that will serve
+the index (:func:`slab_layout_for`).  ``logical`` storage is the
+bucket-major ``[L, 2^K, P, d]`` tensor (``ref`` / ``pallas_interpret``).
+``aligned`` storage is exactly what the TPU kernel reads (``pallas``):
+slabs ``[L*2^K, P', d']`` with ``P'`` and ``d'`` rounded up to the
+128-lane tile and zero-filled, ids ``[L*2^K, 1, P']`` with -1 in the
+added slots, int8 scales ``[L*2^K, 1, P']`` with 0 there
+(:func:`kernel_slabs`).  Serving logical storage on the TPU lays it out
+in every call, which rewrites the whole slab tensor each time.
 """
 
 from __future__ import annotations
@@ -55,6 +65,8 @@ __all__ = [
     "SLAB_DTYPE_CHOICES", "SLAB_DTYPE_ENV_VAR", "slab_dtype_strategy",
     "resolve_slab_dtype", "slab_dtype_of", "slab_itemsize",
     "quantize_slabs", "dequantize_slabs", "lss_topk_slab_dma_bytes",
+    "SLAB_LANE", "slab_layout_for", "kernel_slabs", "is_aligned",
+    "logical_slabs",
 ]
 
 SLAB_DTYPE_CHOICES = ("fp32", "bf16", "int8")
@@ -63,6 +75,8 @@ SLAB_DTYPE_ENV_VAR = "REPRO_LSS_SLAB_DTYPE"
 _DTYPES = {"fp32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
 _NAMES = {jnp.dtype(v): k for k, v in _DTYPES.items()}
 _ITEMSIZE = {"fp32": 4, "bf16": 2, "int8": 1}
+
+SLAB_LANE = 128     # TPU lane width: aligned storage pads P and d to it
 
 
 def _auto_slab_dtype(**_ctx) -> str:
@@ -144,3 +158,65 @@ def lss_topk_slab_dma_bytes(n_tables: int, cap: int, d: int,
     if slab_dtype == "int8":
         per_slab += cap * 4                      # the [P] scale row
     return n_tables * per_slab
+
+
+def slab_layout_for(impl: str | None = None) -> str:
+    """The slab layout an index served by ``impl`` stores: ``aligned``
+    for the TPU kernel (``pallas``), ``logical`` for ``ref`` and
+    ``pallas_interpret``.  ``impl`` resolves as the ``lss_topk`` op's
+    does: explicit > process default > ``$REPRO_KERNEL_IMPL`` >
+    backend."""
+    impl = registry.resolve_impl("lss_topk", impl)
+    return "aligned" if impl == "pallas" else "logical"
+
+
+def kernel_slabs(table_ids: jax.Array, w_bucketed: jax.Array,
+                 w_scale: jax.Array | None, lane: int | None = SLAB_LANE
+                 ) -> tuple[jax.Array, jax.Array, jax.Array | None]:
+    """Logical storage -> the kernel's operands ``(ids [S, 1, P'],
+    slabs [S, P', d'], scales [S, 1, P'] | None)``, S = L*2^K.
+
+    With ``lane``, P and d are padded up to its multiples: the added
+    slots read as empty (id -1, zero row, zero scale), so they score
+    logit 0 and are masked by id like any empty slot.  ``lane=None``
+    only flattens (interpret mode, whose contraction lengths must match
+    the ref's)."""
+    n_tables, n_buckets, cap = table_ids.shape
+    d = w_bucketed.shape[-1]
+    n_slabs = n_tables * n_buckets
+    tids = table_ids.reshape(n_slabs, 1, cap)
+    w = w_bucketed.reshape(n_slabs, cap, d)
+    scales = (None if w_scale is None
+              else w_scale.reshape(n_slabs, 1, cap).astype(jnp.float32))
+    if lane:
+        pad_p, pad_d = (-cap) % lane, (-d) % lane
+        w = jnp.pad(w, ((0, 0), (0, pad_p), (0, pad_d)))
+        tids = jnp.pad(tids, ((0, 0), (0, 0), (0, pad_p)),
+                       constant_values=-1)
+        if scales is not None:
+            scales = jnp.pad(scales, ((0, 0), (0, 0), (0, pad_p)))
+    return tids, w, scales
+
+
+def is_aligned(w_bucketed: jax.Array, d: int) -> bool:
+    """Whether stored slabs are already in the kernel's aligned layout
+    for ``d``-wide queries (read from the shape alone)."""
+    if w_bucketed.ndim != 3:
+        return False
+    _, cap, dw = w_bucketed.shape
+    return cap % SLAB_LANE == 0 and dw % SLAB_LANE == 0 and dw >= d
+
+
+def logical_slabs(w_bucketed: jax.Array, w_scale: jax.Array | None,
+                  table_shape: tuple[int, int, int], d: int
+                  ) -> tuple[jax.Array, jax.Array | None]:
+    """The logical ``[L, 2^K, P, d]`` slabs (and ``[L, 2^K, P]`` scales)
+    of storage in either layout: aligned storage is sliced back to the
+    table's P and the queries' d, logical storage passes through."""
+    if w_bucketed.ndim == 4:
+        return w_bucketed, w_scale
+    n_tables, n_buckets, cap = table_shape
+    w = w_bucketed[:, :cap, :d].reshape(n_tables, n_buckets, cap, d)
+    ws = (None if w_scale is None
+          else w_scale[:, 0, :cap].reshape(n_tables, n_buckets, cap))
+    return w, ws

@@ -31,7 +31,8 @@ Dispatches AND strategy resolutions are recorded at trace time (ops are
 typically called inside ``jax.jit``, whose Python body runs once per
 compilation), so tests and tooling can assert which implementation and
 algorithm actually served a path via :func:`dispatch_log` /
-:func:`last_dispatch`.
+:func:`last_dispatch`.  An op may :func:`record` further choices it
+reads off its operands (``lss_topk.slab_layout``).
 """
 
 from __future__ import annotations
@@ -46,7 +47,7 @@ __all__ = [
     "IMPLS", "ENV_VAR", "KernelOp", "kernel_op", "get_op", "list_ops",
     "resolve_impl", "set_default_impl", "use_impl", "dispatch_log",
     "dispatch_count", "dispatch_counts", "last_dispatch",
-    "reset_dispatch_log",
+    "reset_dispatch_log", "record",
     "KernelStrategy", "kernel_strategy", "get_strategy", "list_strategies",
     "set_default_strategy", "use_strategy",
 ]
@@ -255,6 +256,12 @@ def use_strategy(name: str, choice: str | None):
 
 
 # ------------------------------------------------------ dispatch records --
+
+def record(name: str, choice: str) -> None:
+    """Log a trace-time choice an op made from its operands (e.g.
+    ``("lss_topk.slab_layout", "stored")``) beside its dispatches."""
+    _log.append((name, choice))
+
 
 def dispatch_log(start: int = 0) -> tuple[tuple[str, str], ...]:
     """The ``(op_name, impl)`` dispatches since the last reset, in order,

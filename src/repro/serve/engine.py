@@ -126,6 +126,8 @@ class Engine:
     ``impl`` pins the kernel-registry implementation the LSS heads serve
     with (``ref`` | ``pallas`` | ``pallas_interpret``); None lets the
     registry auto-select by backend (pallas on TPU, ref elsewhere).
+    Every index this engine builds stores its slabs in the layout that
+    impl reads (aligned for ``pallas``; see ``core.lss.build_index``).
     ``dedup`` pins the ``lss_topk`` cross-table dedup strategy
     (``quadratic`` | ``bitonic``); None lets the registry auto-select on
     the candidate count C = L*P.  ``slab_dtype`` pins the bucket-major
@@ -261,7 +263,7 @@ class Engine:
     def fit_from_queries(self, key: jax.Array, q: jax.Array,
                          labels: jax.Array, verbose: bool = False) -> dict:
         index, hist = fit_lss(key, q, labels, self.w, self.b, self.lss_cfg,
-                              verbose=verbose)
+                              verbose=verbose, impl=self.impl)
         # keep references (not copies) to the calibration set: an
         # IndexRefresher snapshots them once to re-learn the hash online
         self.calib = (q, labels)
@@ -274,7 +276,8 @@ class Engine:
         theta = simhash.init_hyperplanes(key, self._w_aug.shape[1],
                                          self.lss_cfg.k_bits,
                                          self.lss_cfg.n_tables)
-        self._set_index(build_index(self._w_aug, theta, self.lss_cfg))
+        self._set_index(build_index(self._w_aug, theta, self.lss_cfg,
+                                    impl=self.impl))
 
     # --------------------------------------------------- index lifecycle --
     @property
@@ -383,7 +386,8 @@ class Engine:
         flip.  ``build_index`` is value-deterministic, so every process
         lands on a bit-identical index without shipping buckets."""
         theta = jnp.asarray(theta, jnp.float32)
-        index = build_index(self._w_aug, theta, self.lss_cfg)
+        index = build_index(self._w_aug, theta, self.lss_cfg,
+                            impl=self.impl)
         return self._swap_prepared(self.prepare_epoch(index))
 
     def pin_epoch(self, epoch: int | None = None) -> int:
@@ -450,7 +454,8 @@ class Engine:
             tp = mesh.shape[self.model_axis]
             if st.sharded is None:
                 stack, w_stack, m_local = shard_index(
-                    self._w_aug, st.index.theta, self.lss_cfg, tp)
+                    self._w_aug, st.index.theta, self.lss_cfg, tp,
+                    impl=self.impl)
                 # lay shard i on device i of the model axis once, here:
                 # the stacks are step arguments, not program constants
                 on_axis = jax.sharding.NamedSharding(
@@ -483,7 +488,8 @@ class Engine:
                                                   self.b[r0:r1])
             local_stack, local_w, m_local = shard_index(
                 w_aug_local, st.index.theta, self.lss_cfg,
-                ctx.n_shards, shard_range=(lo, hi), m_total=m)
+                ctx.n_shards, shard_range=(lo, hi), m_total=m,
+                impl=self.impl)
             stack = assemble_global_stack(ctx, local_stack, ctx.n_shards)
             w_stack = (None if local_w is None else
                        assemble_global_stack(ctx, local_w, ctx.n_shards))

@@ -101,15 +101,21 @@ def make_lss_head(index: LSSIndex, w_aug: jax.Array | None, top_k: int,
 def _mask_index_tail(index: LSSIndex, n_valid: int) -> LSSIndex:
     """Remove local row ids >= ``n_valid`` (vocab padding) from a shard's
     tables: their slots become -1 and their slab rows zero, so padded
-    neurons are simply never retrieved."""
+    neurons are simply never retrieved.  Works on either slab layout:
+    the slab rows are masked by the ids of their own layout."""
     t = index.tables
     ids = jnp.where(t.table_ids < n_valid, t.table_ids, -1)
     tables = LSSTables(ids, t.n_dropped, t.k_bits, t.n_tables, t.capacity)
+    slab_ids = index.slab_ids
+    if slab_ids is not None:
+        slab_ids = jnp.where(slab_ids < n_valid, slab_ids, -1)
+    keep = (ids if slab_ids is None else slab_ids) >= 0
     wb = index.w_bucketed
     if wb is not None:
         # zeroing works for every slab_dtype: an int8 zero code (and a
         # zeroed scale) dequantizes to exactly 0, same as fp32/bf16
-        wb = jnp.where((ids >= 0)[..., None], wb, jnp.zeros_like(wb))
+        wb = jnp.where(keep.reshape(wb.shape[:-1])[..., None], wb,
+                       jnp.zeros_like(wb))
     ws = index.w_scale
     if ws is not None:
         # pad rows carry the NEG_INF sentinel bias, so their per-row
@@ -117,13 +123,13 @@ def _mask_index_tail(index: LSSIndex, n_valid: int) -> LSSIndex:
         # a masked slot is all-zero in BOTH leaves (0 * scale is already
         # exactly 0 in fp32, but interpret-mode buffers and dumps must
         # not carry the sentinel through)
-        ws = jnp.where(ids >= 0, ws, jnp.zeros_like(ws))
-    return LSSIndex(index.theta, tables, wb, ws)
+        ws = jnp.where(keep, ws, jnp.zeros_like(ws))
+    return LSSIndex(index.theta, tables, wb, ws, slab_ids)
 
 
 def shard_index(w_aug: jax.Array, theta: jax.Array, cfg: LSSConfig,
                 n_shards: int, *, shard_range: tuple[int, int] | None = None,
-                m_total: int | None = None):
+                m_total: int | None = None, impl: str | None = None):
     """Split the WOL rows into ``n_shards`` contiguous vocab shards, build
     one local index per shard, and stack the leaves ([n_built, ...]).
 
@@ -144,7 +150,8 @@ def shard_index(w_aug: jax.Array, theta: jax.Array, cfg: LSSConfig,
     addresses from its own row slice and no process ever materializes
     the full ``[m, d]`` weight.  The per-shard indexes (including the
     int8 ``w_scale`` leaf) are bit-identical to the same shards of a
-    full-range build.
+    full-range build.  ``impl`` picks the shards' slab layout, as in
+    ``core.lss.build_index``.
 
     Returns (stacked_index, stacked_w_aug or None, m_local).
     """
@@ -179,7 +186,8 @@ def shard_index(w_aug: jax.Array, theta: jax.Array, cfg: LSSConfig,
     locals_ = []
     for i in range(lo, hi):
         idx = build_local_index(
-            w_aug[(i - lo) * m_local:(i - lo + 1) * m_local], theta, cfg)
+            w_aug[(i - lo) * m_local:(i - lo + 1) * m_local], theta, cfg,
+            impl=impl)
         n_valid = min(max(m - i * m_local, 0), m_local)
         if n_valid < m_local:
             idx = _mask_index_tail(idx, n_valid)

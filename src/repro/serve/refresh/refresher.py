@@ -170,7 +170,7 @@ class IndexRefresher:
         for _ in range(max(1, self.cfg.epochs_per_refresh)):
             self._state, index, info = iul_refit_epoch(
                 self._state, self._q_aug, self._labels, self._w_aug,
-                index, self.engine.lss_cfg)
+                index, self.engine.lss_cfg, self.engine.impl)
         if not bool(jnp.isfinite(self._state.theta).all()):
             raise FloatingPointError(
                 "refit produced non-finite hyperplanes (diverged); "

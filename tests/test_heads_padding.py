@@ -22,6 +22,7 @@ never reach any real query's top-k — interpret == ref at every B.
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
@@ -179,3 +180,39 @@ def test_shard_index_padding_fused_kernel_parity(m, n_shards):
     np.testing.assert_array_equal(np.asarray(ref_l), np.asarray(out_l))
     n_valid = min(max(m - (n_shards - 1) * m_local, 0), m_local)
     assert np.asarray(out_i).max(initial=-1) < max(n_valid, 1)
+
+
+@pytest.mark.parametrize("slab_dtype", ["fp32", "bf16", "int8"])
+def test_shard_index_padding_masks_aligned_storage(slab_dtype):
+    """Shards built for the TPU kernel's aligned layout mask the padded
+    tail in that layout: the tail's ids read -1 in ``slab_ids`` too, and
+    its slabs and scales are, once sliced back, exactly the logical
+    build's, so the shard ranks as the logical one does."""
+    from repro.kernels.lss_topk.slabs import logical_slabs
+    m, n_shards = 13, 3
+    cfg = LSSConfig(k_bits=3, n_tables=2, use_bucket_major=True,
+                    slab_dtype=slab_dtype)
+    w = jax.random.normal(jax.random.PRNGKey(5), (m, D))
+    w_aug = simhash.augment_neurons(w, None)
+    theta = simhash.init_hyperplanes(jax.random.PRNGKey(1), D + 1,
+                                     cfg.k_bits, cfg.n_tables)
+    lo, _, m_local = shard_index(w_aug, theta, cfg, n_shards, impl="ref")
+    al, _, _ = shard_index(w_aug, theta, cfg, n_shards, impl="pallas")
+    last_lo = jax.tree.map(lambda x: x[-1], lo)
+    last_al = jax.tree.map(lambda x: x[-1], al)
+    n_valid = m - (n_shards - 1) * m_local
+    ids = np.asarray(last_al.slab_ids)
+    assert ids.max() < n_valid
+    w_al = np.asarray(last_al.w_bucketed.astype(jnp.float32))
+    assert (w_al[ids[:, 0] < 0] == 0).all()
+    if slab_dtype == "int8":
+        assert (np.asarray(last_al.w_scale)[ids < 0] == 0).all()
+    shape = last_lo.tables.table_ids.shape
+    for a, b in zip(logical_slabs(last_al.w_bucketed, last_al.w_scale,
+                                  shape, D + 1),
+                    (last_lo.w_bucketed, last_lo.w_scale)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    q = jax.random.normal(jax.random.PRNGKey(2), (N_QUERIES, D))
+    for a, b in zip(local_topk(q, last_lo, None, TOP_K, impl="ref"),
+                    local_topk(q, last_al, None, TOP_K, impl="ref")):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

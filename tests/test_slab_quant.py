@@ -164,6 +164,162 @@ def test_build_index_resolves_from_env(monkeypatch):
     assert idx2.w_scale is None
 
 
+# ------------------------------------------------------- slab layout --
+
+def _fitted(m=100, d=12, k_bits=3, n_tables=2, slab_dtype="fp32"):
+    w_aug = simhash.augment_neurons(
+        jax.random.normal(jax.random.PRNGKey(0), (m, d)))
+    theta = simhash.init_hyperplanes(jax.random.PRNGKey(1), d + 1,
+                                     k_bits, n_tables)
+    cfg = LSSConfig(k_bits=k_bits, n_tables=n_tables, slab_dtype=slab_dtype)
+    return w_aug, theta, cfg
+
+
+# The logical index as the IUL rebuild makes it, in one program: the
+# aligned build is one program too, and XLA compiles int8's division by
+# 127 into a product, so an eager build's scales may differ in the last
+# bit from either.
+_BUILD_JIT = jax.jit(build_index, static_argnames=("cfg", "impl"))
+
+
+@pytest.mark.parametrize("slab_dtype", S.SLAB_DTYPE_CHOICES)
+@pytest.mark.parametrize("m,k_bits", [(100, 3), (5000, 7)])
+def test_aligned_build_holds_the_logical_index(slab_dtype, m, k_bits):
+    """Built for ``pallas``, the index stores the kernel's aligned layout
+    and nothing else: the same tables (ids, overflow) as the logical
+    build, its slab rows and scales bit for bit, and empty slots (id
+    -1, zero row, zero scale) in every added lane — for one build chunk
+    of slabs and for several."""
+    w_aug, theta, cfg = _fitted(m=m, k_bits=k_bits, slab_dtype=slab_dtype)
+    lo = _BUILD_JIT(w_aug, theta, cfg, impl="ref")
+    al = build_index(w_aug, theta, cfg, impl="pallas")
+    n_tables, n_buckets, cap = lo.tables.table_ids.shape
+    d = w_aug.shape[1]
+    assert lo.slab_ids is None and lo.w_bucketed.ndim == 4
+    n_slabs = n_tables * n_buckets
+    assert al.w_bucketed.shape == (n_slabs, 128, 128)
+    assert al.w_bucketed.dtype == lo.w_bucketed.dtype
+    assert al.slab_ids.shape == (n_slabs, 1, 128)
+    np.testing.assert_array_equal(al.tables.table_ids, lo.tables.table_ids)
+    np.testing.assert_array_equal(al.tables.n_dropped, lo.tables.n_dropped)
+    assert al.tables.capacity == lo.tables.capacity == cap
+    ids = np.asarray(al.slab_ids)[:, 0]
+    np.testing.assert_array_equal(
+        ids[:, :cap], np.asarray(lo.tables.table_ids).reshape(n_slabs, cap))
+    assert (ids[:, cap:] == -1).all()
+    w = np.asarray(al.w_bucketed.astype(jnp.float32))
+    assert (w[:, cap:] == 0).all() and (w[:, :, d:] == 0).all()
+    wl, sl = S.logical_slabs(al.w_bucketed, al.w_scale,
+                             lo.tables.table_ids.shape, d)
+    np.testing.assert_array_equal(np.asarray(wl), np.asarray(lo.w_bucketed))
+    if slab_dtype == "int8":
+        np.testing.assert_array_equal(np.asarray(sl), np.asarray(lo.w_scale))
+        assert al.w_scale.shape == (n_slabs, 1, 128)
+        assert (np.asarray(al.w_scale)[:, 0, cap:] == 0).all()
+    else:
+        assert al.w_scale is None
+
+
+@pytest.mark.parametrize("impl", ["ref", "pallas"])
+def test_layout_follows_the_resolved_impl(monkeypatch, impl):
+    """No knob of its own: the layout follows the impl the registry
+    resolves for ``lss_topk`` (explicit > process default > env)."""
+    w_aug, theta, cfg = _fitted()
+    rank = 3 if impl == "pallas" else 4
+    assert S.slab_layout_for(impl) == ("aligned" if impl == "pallas"
+                                       else "logical")
+    assert build_index(w_aug, theta, cfg, impl=impl).w_bucketed.ndim == rank
+    with registry.use_impl(impl):
+        assert build_index(w_aug, theta, cfg).w_bucketed.ndim == rank
+    monkeypatch.setenv(registry.ENV_VAR, impl)
+    assert build_index(w_aug, theta, cfg).w_bucketed.ndim == rank
+
+
+@pytest.mark.parametrize("slab_dtype", S.SLAB_DTYPE_CHOICES)
+@pytest.mark.parametrize("build_impl,layout",
+                         [("pallas", "stored"), ("ref", "padded_per_call")])
+def test_kernel_reads_stored_layout_and_logs_it(slab_dtype, build_impl,
+                                                layout):
+    """The kernel impl tells the layouts apart by shape alone and records
+    which it served in the dispatch log; over either it ranks the same
+    ids as the ref over the logical index (logits to within a rounding,
+    as the aligned contraction is longer)."""
+    w_aug, theta, cfg = _fitted(slab_dtype=slab_dtype)
+    index = build_index(w_aug, theta, cfg, impl=build_impl)
+    ref = lss_forward(jax.random.normal(jax.random.PRNGKey(2), (5, 12)),
+                      _BUILD_JIT(w_aug, theta, cfg, impl="ref"), None,
+                      top_k=3, impl="ref")
+    registry.reset_dispatch_log()
+    out = lss_forward(jax.random.normal(jax.random.PRNGKey(2), (5, 12)),
+                      index, None, top_k=3, impl="pallas_interpret")
+    assert [c for k, c in registry.dispatch_log()
+            if k == "lss_topk.slab_layout"] == [layout]
+    for name, r, o in zip(FIELDS, ref, out):
+        if name == "top_logits":
+            np.testing.assert_allclose(np.asarray(o), np.asarray(r),
+                                       rtol=1e-5, atol=1e-5)
+        else:
+            np.testing.assert_array_equal(np.asarray(o), np.asarray(r),
+                                          err_msg=name)
+
+
+def test_unfused_readers_take_aligned_storage():
+    """The unfused bucket-logits path and the ref impl slice aligned
+    storage back to the logical index and see exactly what it holds."""
+    from repro.core.lss import retrieve, sparse_logits_bucketed
+    w_aug, theta, cfg = _fitted(slab_dtype="int8")
+    lo = _BUILD_JIT(w_aug, theta, cfg, impl="ref")
+    al = build_index(w_aug, theta, cfg, impl="pallas")
+    q = jax.random.normal(jax.random.PRNGKey(2), (5, 12))
+    q_aug = simhash.augment_queries(q)
+    _, buckets = retrieve(q_aug, lo)
+    for r, o in zip(sparse_logits_bucketed(q_aug, lo, buckets, impl="ref"),
+                    sparse_logits_bucketed(q_aug, al, buckets, impl="ref")):
+        np.testing.assert_array_equal(np.asarray(r), np.asarray(o))
+    _assert_same(lss_forward(q, lo, None, top_k=3, impl="ref"),
+                 lss_forward(q, al, None, top_k=3, impl="ref"))
+
+
+def _build_with(path: str, impl: str):
+    """The index a build ``path`` makes for an engine pinned to ``impl``."""
+    from repro.serve.engine import Engine
+    from repro.serve.heads import shard_index
+    from repro.serve.refresh import IndexRefresher, RefreshConfig
+
+    w = jax.random.normal(jax.random.PRNGKey(0), (96, 12))
+    eng = Engine(None, w, None,
+                 LSSConfig(k_bits=3, n_tables=2, iul_epochs=1,
+                           iul_batch=32, iul_inner_steps=1),
+                 top_k=3, buckets=(4,), impl=impl)
+    if path == "shard_index":
+        theta = simhash.init_hyperplanes(jax.random.PRNGKey(1), 13, 3, 2)
+        stack, _, _ = shard_index(eng._w_aug, theta, eng.lss_cfg, 2,
+                                  impl=impl)
+        return jax.tree.map(lambda x: x[-1], stack)
+    if path == "swap_from_theta":
+        eng.swap_from_theta(
+            simhash.init_hyperplanes(jax.random.PRNGKey(1), 13, 3, 2))
+        return eng.index
+    q = jax.random.normal(jax.random.PRNGKey(2), (64, 12))
+    labels = jax.random.randint(jax.random.PRNGKey(3), (64, 2), 0, 96)
+    eng.fit_from_queries(jax.random.PRNGKey(4), q, labels)   # IUL rebuild
+    if path == "fit":
+        return eng.index
+    return IndexRefresher(eng, None, RefreshConfig())._refit()[0]
+
+
+@pytest.mark.parametrize("impl", ["ref", "pallas"])
+@pytest.mark.parametrize("path",
+                         ["shard_index", "swap_from_theta", "fit", "refresh"])
+def test_every_build_path_stores_its_impls_layout(path, impl):
+    index = _build_with(path, impl)
+    if impl == "pallas":
+        assert index.w_bucketed.ndim == 3 and index.slab_ids is not None
+        assert S.is_aligned(index.w_bucketed, 13)
+    else:
+        assert index.w_bucketed.ndim == 4 and index.slab_ids is None
+
+
 # ------------------------------------------------ refit requantization --
 
 def test_refit_requantizes_and_invalidates_steps():

@@ -3,7 +3,10 @@
 The TPU compiler is installed even where no chip is attached, so these
 tests lower the fused ``lss_topk`` kernel (and the ``simhash_codes``
 kernel the IUL fit retrieves through) at qwen2-0.5b's head widths and
-compile them for one chip of a ``v5e:2x2`` topology.  Mosaic refuses
+compile them for one chip of a ``v5e:2x2`` topology.  One more lowers
+the LSS head's whole jitted step, to see that an index stored in the
+kernel's aligned layout reaches the kernel without a slab-sized pad or
+copy, as logical storage does not.  Mosaic refuses
 what interpret mode accepts: unaligned DMA slices, scalar stores to
 VMEM, bool transposes, rank-changing shape casts, and more VMEM than the
 kernel asks for.  Nothing runs, so these say nothing about results.
@@ -16,20 +19,26 @@ tests.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.lss import LSSIndex
+from repro.core.tables import LSSTables
 from repro.kernels.lss_topk.kernel import lss_topk_pallas
 from repro.kernels.lss_topk.ops import lss_topk_vmem_bytes, lss_topk_vmem_limit
 from repro.kernels.simhash_codes.kernel import simhash_codes_pallas
+from repro.serve.heads import make_lss_head
 
 # qwen2-0.5b's LSS head: d_aug 897 -> 1024 lanes, K=10 bits, L=1 table,
 # P = 304 slots per bucket -> 384 lanes (configs/qwen2_0_5b.py)
 D, K_BITS, N_TABLES, CAP, TOP_K = 1024, 10, 1, 384, 10
 N_SLABS = N_TABLES * 2 ** K_BITS
+D_AUG, CAP_LOGICAL, D_MODEL = 897, 304, 896
 
 
 @pytest.fixture(scope="module")
@@ -96,3 +105,58 @@ def test_simhash_codes_compiles_for_v5e(one_chip, no_persistent_cache):
         x, theta, k_bits=K_BITS, n_tables=N_TABLES,
         block_b=block_b).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _lss_index(one_chip, layout: str) -> LSSIndex:
+    """qwen2-0.5b's fp32 LSS index as shapes, in either slab layout."""
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tables = LSSTables(
+        arg((N_TABLES, 2 ** K_BITS, CAP_LOGICAL), jnp.int32),
+        arg((N_TABLES,), jnp.int32), K_BITS, N_TABLES, CAP_LOGICAL)
+    theta = arg((D_AUG, K_BITS * N_TABLES), jnp.float32)
+    if layout == "stored":
+        return LSSIndex(theta, tables, arg((N_SLABS, CAP, D), jnp.float32),
+                        None, arg((N_SLABS, 1, CAP), jnp.int32))
+    return LSSIndex(theta, tables, arg(
+        (N_TABLES, 2 ** K_BITS, CAP_LOGICAL, D_AUG), jnp.float32))
+
+
+_MOVE = re.compile(r"= \w+\[([\d,]*)\]\S* (?:pad|copy)\(")
+
+
+def _slab_sized_moves(hlo: str, n_elems: int) -> list[str]:
+    """The pad and copy instructions of ``hlo`` with at least
+    ``n_elems`` elements (a copy's result is its operand's size, and a
+    pad's is larger)."""
+    found = []
+    for line in hlo.splitlines():
+        m = _MOVE.search(line)
+        if m and np.prod([int(x) for x in m.group(1).split(",") if x]
+                         ) >= n_elems:
+            found.append(line.strip())
+    return found
+
+
+@pytest.mark.parametrize("layout", ["stored", "padded_per_call"])
+def test_lss_head_step_moves_no_slab_tensor(one_chip, no_persistent_cache,
+                                            layout):
+    """The decode step's LSS head at qwen2-0.5b's widths, 32 rows: over
+    aligned storage the optimized program neither pads nor copies the
+    slab tensor and its temporaries stay below its bytes; over logical
+    storage it pads it in every call."""
+    head = make_lss_head(_lss_index(one_chip, layout), None, TOP_K,
+                         impl="pallas")
+    q = jax.ShapeDtypeStruct((32, D_MODEL), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(head.with_operands).lower(q, *head.operands).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    slab_elems = N_TABLES * 2 ** K_BITS * CAP_LOGICAL * D_AUG
+    moves = _slab_sized_moves(hlo, slab_elems)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if layout == "stored":
+        assert moves == []
+        assert temp < N_SLABS * CAP * D * 4
+    else:
+        assert any(" pad(" in line for line in moves), moves

@@ -2,10 +2,11 @@
 against the plain reference (``bench.reference``) on the same inputs.
 
 Served models (decode cells).  For a sample of finished sessions, the
-reference runs one full forward pass over each prompt with its served
-tokens, in fp32 at HIGHEST precision, and reads at every served position
-the gap by which the served token's logit lies below the best logit the
-head's semantics allow there:
+reference (``hidden`` of the architecture's ``bench/models/<model_type>.py``)
+runs one full forward pass over each prompt with its served tokens, in
+fp32 at HIGHEST precision, and reads at every served position the gap by
+which the served token's logit (on the architecture's ``head_table``)
+lies below the best logit the head's semantics allow there:
 
 * full head: the best over the whole vocabulary;
 * LSS head: the best over the bucket that holds the served token (the
@@ -40,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import reference as R
+from bench import spec
 
 NEG = -jnp.inf
 
@@ -102,21 +104,22 @@ def decode_numbers(params: dict, cfg: dict, head: str, sessions: list,
     Returns ``{"gap": ..., "miss": ...}`` (``miss`` for LSS only), read
     from the served tokens, or with ``control`` from the tokens the
     control would serve at the same positions."""
-    w = params["embed"]
+    arch = spec.arch(cfg)
+    w = arch.head_table(params)
     lss = head != "full"
     dummy = jnp.zeros((1,), jnp.int32)
     gaps, misses = [], []
     for prompt, served in sessions:
         seq = np.concatenate([prompt, served[:-1]]).astype(np.int32)
         p = len(prompt)
-        h = R.lm_hidden(params, cfg, seq, "highest", pad_to)[p - 1:]
+        h = arch.hidden(params, cfg, seq, "highest", pad_to)[p - 1:]
         qc = index.query_codes(h) if lss else dummy
         ncodes = index.codes if lss else dummy
         keep = index.keep if lss else dummy.astype(bool)
         if control is None:
             chosen = jnp.asarray(served, jnp.int32)
         else:
-            hc = R.lm_hidden(params, cfg, seq, control, pad_to)[p - 1:]
+            hc = arch.hidden(params, cfg, seq, control, pad_to)[p - 1:]
             qcc = index.query_codes(hc) if lss else dummy
             chosen = _control_choice(hc, w, qcc, ncodes, keep, lss, control)
         g, m = _gaps(h, w, chosen, qc, ncodes, keep, lss)
@@ -220,7 +223,8 @@ def compare(params: dict, seed: int, cfg: dict, mix: dict, served,
     from bench import weights
     head = mix["head"]
     lss = cfg["lss"]
-    table = params["embed"] if mix["kind"] == "decode" else params["w_out"]
+    table = (spec.arch(cfg).head_table(params) if mix["kind"] == "decode"
+             else params["w_out"])
     index = None
     if head != "full":
         index = LSSIndexRef(weights.hash_key(seed), table, lss,

@@ -4,7 +4,9 @@ The timed path is the program's own: ``AsyncRuntime.submit_decode`` ->
 ``DecodeScheduler`` (prefill per power-of-two bucket, the first token
 ranked by the head, then one fused ``decode_step_pooled -> head`` step
 per token over every slot).  Closed loop: each of ``clients`` sends its
-next session when its last one has ended.
+next session when its last one has ended.  The weights from the seed and
+the program's configuration are the architecture's
+(``bench/models/<model_type>.py``).
 
 Every time is the benchmark's own host clock: a client stamps a session
 as it hands it to ``submit_decode`` and each token as its iteration of
@@ -20,7 +22,7 @@ from typing import NamedTuple
 
 import numpy as np
 
-from bench import traffic, weights
+from bench import spec, traffic, weights
 from bench.log import note
 
 # sessions drawn up front: more than any closed loop here completes
@@ -33,22 +35,6 @@ class SessionRecord(NamedTuple):
     token_times: np.ndarray      # host clock as the client received each
     tokens: np.ndarray
     failed: bool
-
-
-def transformer_config(cfg: dict):
-    """The program's ``TransformerConfig`` for a configuration file."""
-    import jax.numpy as jnp
-    from repro.models.transformer import TransformerConfig
-    if cfg.get("torch_dtype") != "bfloat16":
-        raise ValueError("decode cells serve bf16 models")
-    return TransformerConfig(
-        name=cfg["name"], n_layers=cfg["num_hidden_layers"],
-        d_model=cfg["hidden_size"], n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-        qkv_bias=bool(cfg.get("qkv_bias")), qk_norm=False,
-        rope_base=float(cfg["rope_theta"]),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]), dtype=jnp.bfloat16)
 
 
 def warm_lengths(lo: int, hi: int) -> list[int]:
@@ -77,11 +63,12 @@ class DecodeCell:
         from repro.serve import AsyncRuntime
         from repro.serve.engine import LMDecoder
         cfg, mix = self.cfg, self.mix
-        self.params = weights.make_lm_params(cfg, self.seed)
+        arch = spec.arch(cfg)
+        self.params = arch.make_params(cfg, self.seed)
         note("weights made")
         lss = cfg["lss"]
         self.dec = LMDecoder(
-            self.params, transformer_config(cfg),
+            self.params, arch.program_config(cfg),
             LSSConfig(k_bits=lss["k_bits"], n_tables=lss["n_tables"],
                       capacity=lss["capacity"]),
             max_streams=mix["max_streams"], max_len=mix["max_len"],

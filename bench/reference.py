@@ -1,11 +1,10 @@
 """Plain references, written from the published descriptions and
 independent of the program: nothing here imports the system under test.
 
-* ``lm_hidden``: the Qwen2 decoder (arXiv:2407.10671; HF ``Qwen2Model``):
-  RMSNorm (eps from the config), rotary embeddings on the two halves of
-  each head (``rotate_half``), grouped-query attention with QKV bias and
-  a causal mask, a SwiGLU MLP, a final RMSNorm.  One full forward pass
-  over the whole sequence, no cache.
+* a language model's body: ``hidden`` of its architecture's module,
+  ``bench/models/<model_type>.py``, built from the pieces here that
+  every LM shares: ``mm``, ``rms_norm`` and ``rope`` (rotary embeddings
+  on the two halves of each head, HF ``rotate_half``).
 * ``lss_*``: Algorithm 2 of arXiv:2007.01230 on the same index: a
   neuron ``[w, b]`` and a query ``[q, 0]`` fall in the same bucket of
   table l when the signs of their K projections on table l's hyperplanes
@@ -58,14 +57,14 @@ def mm(a: jax.Array, b: jax.Array, prec: str) -> jax.Array:
     raise ValueError(f"unknown precision {prec!r}")
 
 
-# ------------------------------------------------------------ the LM --
+# -------------------------------------------------- shared by the LMs --
 
-def _rms_norm(x, scale, eps):
+def rms_norm(x, scale, eps):
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return x / jnp.sqrt(var + eps) * scale.astype(jnp.float32)
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """x [S, H, D]: rotate the pair (x_i, x_{i + D/2}) by pos * theta^(-2i/D)."""
     d = x.shape[-1]
     inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
@@ -75,61 +74,6 @@ def _rope(x, pos, theta):
     half = d // 2
     rot = jnp.concatenate([-x[..., half:], x[..., :half]], -1)
     return x * cos + rot * sin
-
-
-@functools.partial(jax.jit, static_argnames=("shape", "prec"))
-def _lm_hidden(params, tokens, shape, prec):
-    (n_h, n_kv, hd, eps, theta) = shape
-    s = tokens.shape[0]
-    x = params["embed"][tokens].astype(jnp.float32)                # [S, d]
-    pos = jnp.arange(s)
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    group = n_h // n_kv
-
-    def layer(x, lp):
-        h = _rms_norm(x, lp["ln1"], eps)
-        q = mm(h, lp["wq"], prec) + lp["bq"].astype(jnp.float32)
-        k = mm(h, lp["wk"], prec) + lp["bk"].astype(jnp.float32)
-        v = mm(h, lp["wv"], prec) + lp["bv"].astype(jnp.float32)
-        q = _rope(q.reshape(s, n_h, hd), pos, theta)
-        k = _rope(k.reshape(s, n_kv, hd), pos, theta)
-        v = v.reshape(s, n_kv, hd)
-        outs = []
-        for head in range(n_h):                  # query head -> its KV group
-            kv = head // group
-            sc = mm(q[:, head], k[:, kv].T, prec) / jnp.sqrt(jnp.float32(hd))
-            sc = jnp.where(causal, sc, -jnp.inf)
-            outs.append(mm(jax.nn.softmax(sc, axis=-1), v[:, kv], prec))
-        x = x + mm(jnp.concatenate(outs, -1), lp["wo"], prec)
-        h = _rms_norm(x, lp["ln2"], eps)
-        g = mm(h, lp["w_gate"], prec)
-        u = mm(h, lp["w_up"], prec)
-        x = x + mm(jax.nn.silu(g) * u, lp["w_down"], prec)
-        return x, None
-
-    x, _ = jax.lax.scan(layer, x, params["layers"])
-    return _rms_norm(x, params["final_norm"], eps)
-
-
-def lm_hidden(params: dict, cfg: dict, tokens: np.ndarray,
-              prec: str = "highest", pad_to: int | None = None) -> jax.Array:
-    """Final-norm hidden states ``[S, d]`` of a full causal forward pass.
-    ``pad_to`` pads the sequence at its end (causality keeps the real
-    positions exact) so that sequences of any length share one program."""
-    toks = np.asarray(tokens, np.int32)
-    n = toks.shape[0]
-    if pad_to is not None and pad_to > n:
-        toks = np.concatenate([toks, np.zeros(pad_to - n, np.int32)])
-    layers = dict(params["layers"])
-    if not cfg.get("qkv_bias"):
-        for b, w in (("bq", "wq"), ("bk", "wk"), ("bv", "wv")):
-            layers[b] = jnp.zeros(layers[w].shape[::2], jnp.float32)
-    shape = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-             cfg["head_dim"], float(cfg["rms_norm_eps"]),
-             float(cfg["rope_theta"]))
-    p = {"embed": params["embed"], "layers": layers,
-         "final_norm": params["final_norm"]}
-    return _lm_hidden(p, jnp.asarray(toks), shape, prec)[:n]
 
 
 # ------------------------------------------------------- the LSS index --

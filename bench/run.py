@@ -6,9 +6,10 @@
 From the root of a checkout.  The cell (``BENCHMARK.json``'s
 ``workloads``) names a configuration and a traffic mix; the run
 
-1. refuses any device that is not a TPU with published peaks
-   (``bench/peaks.py``), and a host with fewer chips than the cell asks
-   for: exit code 2, no result line;
+1. refuses an unknown cell, a language model whose ``model_type`` has
+   no ``bench/models/<model_type>.py``, any device that is not a TPU
+   with published peaks (``bench/peaks.py``), and a host with fewer
+   chips than the cell asks for: exit code 2, no result line;
 2. builds the cell from the seed, on the device: weights, index, the
    program's server, every request of the run;
 3. warms exactly the shapes the mix uses, then pre-rolls its traffic;

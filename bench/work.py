@@ -2,7 +2,8 @@
 
 Every count here is what the algorithm needs for the real rows of a
 call, at the storage dtype of the operands as the program holds them:
-bf16 weights and KV for the language model body, fp32 for the WOL
+bf16 weights and KV for the language model body (counted by its
+architecture's module, ``bench/models/<model_type>.py``), fp32 for the WOL
 (``Engine`` holds ``w`` in fp32, and the LSS index its slabs in fp32).
 Padded shapes never enter: a later change that moves the padding, or the
 storage, is read against the same work.
@@ -14,6 +15,8 @@ the files under ``bench/configs/``.
 from __future__ import annotations
 
 from typing import NamedTuple, Sequence
+
+from bench import spec
 
 FP32 = 4
 BF16 = 2
@@ -81,23 +84,7 @@ def head_call(cfg: dict, head: str, rows: float) -> Work:
     return lss_call(cfg, rows) if head == "lss" else full_call(cfg, rows)
 
 
-# ------------------------------------------------------- the LM body --
-
-def _layer_weights(cfg: dict) -> int:
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    nq = cfg["num_attention_heads"] * cfg["head_dim"]
-    nkv = cfg["num_key_value_heads"] * cfg["head_dim"]
-    n = d * nq + 2 * d * nkv + nq * d + 3 * d * f
-    if cfg.get("qkv_bias"):
-        n += nq + 2 * nkv
-    return n
-
-
-def kv_bytes_per_position(cfg: dict) -> int:
-    """bf16 keys and values of one position across every layer."""
-    return (cfg["num_hidden_layers"] * 2 * cfg["num_key_value_heads"]
-            * cfg["head_dim"] * BF16)
-
+# ------------------------------------------------------------ a step --
 
 def decode_steps(cfg: dict, head: str, n_steps: int,
                  contexts: Sequence[int]) -> Work:
@@ -105,23 +92,13 @@ def decode_steps(cfg: dict, head: str, n_steps: int,
     contexts are ``contexts``: ``contexts[i]`` is the number of cached
     positions row i attends to, its new one included.
 
-    Operations: every layer's weights once per row, attention scores and
-    values over the row's context, the head per row.  Bytes: the bf16
-    layer weights and norms once per step, the row's embedding, its
-    cached KV read and its new position written (together its context),
-    and the head's reads: its per-call operands once per step."""
-    rows = len(contexts)
-    n_l = cfg["num_hidden_layers"]
-    d = cfg["hidden_size"]
-    nq = cfg["num_attention_heads"] * cfg["head_dim"]
-    w = _layer_weights(cfg)
-    ctx = float(sum(contexts))
-    flops = 2.0 * w * n_l * rows + 4.0 * nq * n_l * ctx
-    norms = (2 * n_l + 1) * d * FP32
-    nbytes = (n_steps * (w * n_l * BF16 + norms) + rows * d * BF16
-              + kv_bytes_per_position(cfg) * ctx)
-    h = head_call(cfg, head, rows / n_steps)
-    return Work(flops + n_steps * h.flops, nbytes + n_steps * h.nbytes)
+    The body's work is its architecture's (``body_work`` of
+    ``bench/models/<model_type>.py``); the head adds its operations per
+    row and its reads: its per-call operands once per step."""
+    body = spec.arch(cfg).body_work(cfg, n_steps, contexts)
+    h = head_call(cfg, head, len(contexts) / n_steps)
+    return Work(body.flops + n_steps * h.flops,
+                body.nbytes + n_steps * h.nbytes)
 
 
 def decode_step(cfg: dict, head: str, contexts: Sequence[int]) -> Work:

@@ -1,17 +1,19 @@
-"""The harness finds every configuration, mix, limit and per-layer metric
-by its name, so a new one is added as files and entries alone; and on a
-host without a chip, or in a directory without the program, the run
+"""The harness finds every configuration, mix, limit, per-layer metric
+and language-model architecture by its name, so a new one is added as
+files and entries alone; and on a host without a chip, in a directory
+without the program, or for an architecture it has no module of, the run
 refuses with no result line."""
 
 import json
 import os
+import shutil
 import subprocess
 import sys
 
 import pytest
 
-from bench import spec
-from tinycells import REPO, make_root
+from bench import peaks, run, spec
+from tinycells import REPO, TINY_LM, jax_config_restored, make_root
 
 
 def test_every_cell_of_the_benchmark_resolves():
@@ -100,7 +102,7 @@ def test_cpu_host_exits_nonzero_with_no_result():
 
 def test_directory_without_the_program_exits_nonzero(tmp_path):
     (tmp_path / "bench").mkdir()
-    for sub in ("configs", "traffic", "limits", "metrics"):
+    for sub in ("configs", "traffic", "limits", "metrics", "models"):
         (tmp_path / "bench" / sub).symlink_to(REPO / "bench" / sub)
     for p in (REPO / "bench").glob("*.py"):
         (tmp_path / "bench" / p.name).write_text(p.read_text())
@@ -108,3 +110,81 @@ def test_directory_without_the_program_exits_nonzero(tmp_path):
         (REPO / "BENCHMARK.json").read_text())
     p = _run(tmp_path)
     assert p.returncode != 0 and _no_result(p.stdout), p.stdout
+
+
+def _add_lm_cell(root, model_type, cell="tiny-arch.decode.full"):
+    """A configuration of ``model_type`` and its cell, served by the full
+    head, with the mix, limits and entries of ``tiny-lm.decode.full``."""
+    cfg = dict(TINY_LM, name="tiny-arch")
+    if model_type is None:
+        del cfg["model_type"]
+    else:
+        cfg["model_type"] = model_type
+    (root / "bench" / "configs" / "tiny-arch.json").write_text(json.dumps(cfg))
+    shutil.copy(root / "bench" / "limits" / "tiny-lm.decode.full.json",
+                root / "bench" / "limits" / f"{cell}.json")
+    bm = json.loads((root / "BENCHMARK.json").read_text())
+    bm["configs"].append({"name": "tiny-arch", "source": "tiny",
+                          "file": "bench/configs/tiny-arch.json",
+                          "reduced": [], "why": "t"})
+    bm["workloads"].append({"name": cell, "config": "tiny-arch",
+                            "traffic": "tiny-decode.full", "chips": 1,
+                            "why": "t"})
+    for m in bm["end_to_end"] + bm["per_layer"]:
+        if "tiny-lm.decode.full" in m.get("workloads", ()):
+            m["workloads"].append(cell)
+    (root / "BENCHMARK.json").write_text(json.dumps(bm))
+    return cfg
+
+
+def _run_cell(root, cell, trace):
+    return run.main(["--workload", cell, "--seed", str(2 ** 31 + 5),
+                     "--seconds", "1", "--trace", str(trace)],
+                    require_chip=False, root=root)
+
+
+def test_added_architecture_is_found_by_its_model_type(tmp_path, capsys,
+                                                       monkeypatch):
+    """A model_type the repository does not know, added as one module
+    under ``bench/models/`` with a configuration, limits and entries,
+    serves its cell to a correct result, and the per-layer readers count
+    its body with its own ``body_work``."""
+    root = make_root(tmp_path)
+    os.unlink(root / "bench" / "models")
+    shutil.copytree(REPO / "bench" / "models", root / "bench" / "models",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (root / "bench" / "models" / "tinyarch.py").write_text(
+        (REPO / "bench" / "models" / "qwen2.py").read_text() + """
+
+BODY_CALLS = []
+_body_work = body_work
+
+
+def body_work(cfg, n_steps, contexts):
+    BODY_CALLS.append(n_steps)
+    return _body_work(cfg, n_steps, contexts)
+""")
+    cfg = _add_lm_cell(root, "tinyarch")
+    assert not (REPO / "bench" / "models" / "tinyarch.py").exists()
+    monkeypatch.setitem(peaks.PEAKS, "cpu", peaks.PEAKS["TPU v5 lite"])
+    with jax_config_restored():
+        assert _run_cell(root, "tiny-arch.decode.full", trace=1) == 0
+    res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert res["correct"] is True, res
+    assert res["attempted"] > 0 and res["failed"] == 0
+    arch = spec.arch(cfg)
+    assert arch.__file__ == str(root / "bench" / "models" / "tinyarch.py")
+    assert arch.BODY_CALLS                      # decode_step_mfu read it
+    assert "decode_step_mfu" in res["metrics"]
+
+
+@pytest.mark.parametrize("model_type", [None, "no_such_arch"])
+def test_architecture_without_a_module_exits_nonzero(tmp_path, capsys,
+                                                     model_type):
+    root = make_root(tmp_path)
+    _add_lm_cell(root, model_type)
+    with jax_config_restored():
+        assert _run_cell(root, "tiny-arch.decode.full", trace=0) != 0
+    out, err = capsys.readouterr()
+    assert _no_result(out), out
+    assert "model_type" in err

@@ -27,6 +27,7 @@ def reduced_model_cfg(arch_id: str):
         if full.moe_style != "none":
             kw.update(n_experts=4, n_experts_padded=4, moe_top_k=2,
                       moe_d_ff=64, capacity_factor=4.0,
+                      moe_norm_topk=full.moe_norm_topk,
                       shared_expert_ff=96 if full.shared_expert_ff else 0)
         return TransformerConfig(**kw)
     if isinstance(full, GCNConfig):

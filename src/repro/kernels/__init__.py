@@ -20,4 +20,6 @@ from repro.kernels import registry
 from repro.kernels.simhash_codes import simhash_codes
 from repro.kernels.bucket_logits import bucket_logits
 from repro.kernels.lss_topk import lss_topk
-__all__ = ["registry", "simhash_codes", "bucket_logits", "lss_topk"]
+from repro.kernels.moe_gmm import moe_gmm
+__all__ = ["registry", "simhash_codes", "bucket_logits", "lss_topk",
+           "moe_gmm"]

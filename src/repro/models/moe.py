@@ -1,20 +1,34 @@
-"""Mixture-of-Experts layer: top-k routing + argsort dispatch.
+"""Mixture-of-Experts layer: top-k routing and two dispatches.
 
-Dispatch is sort-based (gather/scatter), NOT the GShard one-hot einsum:
-the one-hot formulation inflates HLO FLOPs by O(S·E·C·d) and would poison
-the roofline "useful compute" ratio; gathers are ~free in cost_analysis
-and on TPU lower to dynamic-slice streams.
+* Training (:func:`moe_ffn`) is sort-based GShard dispatch
+  (gather/scatter, NOT the one-hot einsum: the one-hot formulation
+  inflates HLO FLOPs by O(S·E·C·d) and would poison the roofline "useful
+  compute" ratio).  Static shapes: per-expert capacity C =
+  ceil(tokens·top_k/E) · capacity_factor; overflow tokens are dropped
+  (their combine weight is 0), underflow slots are zero-padded.  Experts
+  are sharded over the ``model`` mesh axis by the launcher; GSPMD inserts
+  the all-to-alls at the scatter/gather boundaries.
+* Serving (:func:`moe_ffn_serving`, prefill and decode) drops nothing:
+  the (token, expert) assignments are sorted by expert and the experts'
+  SwiGLU runs as grouped products (``kernels.moe_gmm``) with no capacity,
+  so a row's output never depends on its batch-mates or on padding.
+  Where the launcher splits the experts over a ``model`` mesh axis,
+  prefill and decode keep the capacity dispatch: GSPMD cannot partition
+  the grouped products' kernel.
 
-Static shapes throughout: per-expert capacity C = ceil(tokens·top_k/E) ·
-capacity_factor; overflow tokens are dropped (their combine weight is 0),
-underflow slots are zero-padded.  Experts are sharded over the ``model``
-mesh axis by the launcher; GSPMD inserts the all-to-alls at the
-scatter/gather boundaries.
+In prefill and decode a layer may hold only some of the routed experts
+(``n_held`` from ``held_offset``; training holds every expert), as one
+chip of an expert-parallel group does: the router still scores every
+expert and picks its top-k over all of them, and the layer adds only its
+held experts' part; what experts held elsewhere would add is left out
+(no exchange stands in for them).
 
 Supports the two assigned MoE archs:
-  * qwen2-moe: 60 routed (padded to 64 for even sharding) top-4,
-    renormalised probs, + 1 shared expert with a sigmoid gate.
-  * arctic: 128 routed top-2 + a DENSE residual MLP in parallel.
+  * qwen2-moe: 60 routed top-4 (padded to 64 for even sharding in
+    training), the top-4 softmax weights as they are (the config's
+    ``norm_topk_prob`` is false), + 1 shared expert with a sigmoid gate.
+  * arctic: 128 routed top-2, renormalised, + a DENSE residual MLP in
+    parallel.
 """
 
 from __future__ import annotations
@@ -25,6 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels.moe_gmm import moe_gmm
 from repro.utils.sharding import maybe_shard
 
 
@@ -42,6 +57,17 @@ class MoEConfig(NamedTuple):
     # all-gather the scatter updates (measured 16 GB/device/layer on
     # qwen2-moe train_4k; EXPERIMENTS.md §Perf hillclimb 1).
     n_groups: int = 1
+    # renormalise the top-k weights to sum to one (qwen2-moe: no)
+    norm_topk: bool = True
+    # the routed experts this layer holds: n_held from held_offset
+    # (0 = every physical expert)
+    n_held: int = 0
+    held_offset: int = 0
+
+    @property
+    def n_local(self) -> int:
+        """Expert stacks this layer holds."""
+        return self.n_held or self.n_experts_padded
 
 
 def router_topk(x: jax.Array, w_router: jax.Array, cfg: MoEConfig
@@ -58,7 +84,8 @@ def router_topk(x: jax.Array, w_router: jax.Array, cfg: MoEConfig
         logits = jnp.where(pad_mask[None], -1e30, logits)
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_e = jax.lax.top_k(probs, cfg.top_k)          # [T, k]
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    if cfg.norm_topk:
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
     # Switch-style aux loss: E * sum_e f_e * p_e
     me = probs.mean(0)                                       # [Ep]
     ce = jnp.zeros((cfg.n_experts_padded,)).at[top_e.reshape(-1)].add(
@@ -153,6 +180,48 @@ def moe_ffn(x: jax.Array, params: dict, cfg: MoEConfig
     return out.reshape(t, d), aux
 
 
+def moe_ffn_serving(x: jax.Array, params: dict, cfg: MoEConfig,
+                    layer: jax.Array | int
+                    ) -> tuple[jax.Array, jax.Array]:
+    """Drop-free MoE FFN on flattened tokens ``[T, D]`` -> (out, routed).
+
+    params: this layer's router [D, Ep] and every layer's stacks of the
+    held experts, w_gate/w_up [L, El, D, F], w_down [L, El, F, D], which
+    the grouped products read at ``layer`` where they lie (a layer scan
+    then copies no slice of them out).
+
+    The (token, slot) assignments are sorted by held expert (assignments
+    to experts held elsewhere sort last, into no group); each held
+    expert's SwiGLU runs on exactly its rows as three grouped products
+    with fp32 accumulation; each token sums its slots' outputs, weighted
+    by the routing weights, in slot order.  Every step is row-local, so
+    a token's output is the same whatever else the call holds.
+
+    ``routed``: int32 ``[2]`` — the assignments to held experts, and the
+    held experts that received at least one.
+    """
+    t, d = x.shape
+    k = cfg.top_k
+    top_e, top_p, _ = router_topk(x, params["router"], cfg)
+    local = top_e - cfg.held_offset
+    held = (local >= 0) & (local < cfg.n_local)
+    group = jnp.where(held, local, cfg.n_local).reshape(-1)      # [T*k]
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.zeros((cfg.n_local + 1,), jnp.int32).at[group].add(1)
+    sizes = sizes[:cfg.n_local]
+    wdt = params["w_gate"].dtype
+    xs = x[order // k].astype(wdt)                               # sorted
+    g = moe_gmm(xs, params["w_gate"], sizes, layer=layer)
+    u = moe_gmm(xs, params["w_up"], sizes, layer=layer)
+    h = (jax.nn.silu(g) * u).astype(wdt)
+    y = moe_gmm(h, params["w_down"], sizes, layer=layer)         # fp32
+    y = y[jnp.argsort(order)].reshape(t, k, d)                   # slot order
+    w = jnp.where(held, top_p, 0).astype(jnp.float32)
+    out = jnp.sum(y * w[..., None], axis=1)
+    routed = jnp.stack([jnp.sum(held), jnp.sum(sizes > 0)]).astype(jnp.int32)
+    return out.astype(x.dtype), routed
+
+
 def moe_ffn_dense_oracle(x: jax.Array, params: dict, cfg: MoEConfig
                          ) -> jax.Array:
     """No-capacity-drop oracle: run every expert on every token, mask by
@@ -168,13 +237,16 @@ def moe_ffn_dense_oracle(x: jax.Array, params: dict, cfg: MoEConfig
 
 
 def init_moe_params(key: jax.Array, cfg: MoEConfig, dtype=jnp.float32) -> dict:
+    """Router ``[D, Ep]`` over every expert; expert stacks of the held
+    ones, ``[El, ...]``."""
     k1, k2, k3, k4 = jax.random.split(key, 4)
     d, f, ep = cfg.d_model, cfg.d_ff, cfg.n_experts_padded
+    el = cfg.n_local
     s_in = d ** -0.5
     s_ff = f ** -0.5
     return {
         "router": (jax.random.normal(k1, (d, ep)) * s_in).astype(jnp.float32),
-        "w_gate": (jax.random.normal(k2, (ep, d, f)) * s_in).astype(dtype),
-        "w_up": (jax.random.normal(k3, (ep, d, f)) * s_in).astype(dtype),
-        "w_down": (jax.random.normal(k4, (ep, f, d)) * s_ff).astype(dtype),
+        "w_gate": (jax.random.normal(k2, (el, d, f)) * s_in).astype(dtype),
+        "w_up": (jax.random.normal(k3, (el, d, f)) * s_in).astype(dtype),
+        "w_down": (jax.random.normal(k4, (el, f, d)) * s_ff).astype(dtype),
     }

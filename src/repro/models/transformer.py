@@ -26,7 +26,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P  # noqa: F401
 
 from repro.models import layers as L
-from repro.models.moe import MoEConfig, init_moe_params, moe_ffn
+from repro.models.moe import (MoEConfig, init_moe_params, moe_ffn,
+                              moe_ffn_serving)
 from repro.utils.sharding import maybe_shard, mesh_axis_size
 
 
@@ -51,6 +52,11 @@ class TransformerConfig(NamedTuple):
     moe_d_ff: int = 0
     shared_expert_ff: int = 0     # qwen2-moe shared expert hidden size
     capacity_factor: float = 1.25
+    moe_norm_topk: bool = True    # renormalise the top-k routing weights
+    # the routed experts a layer holds, as one chip of an expert-parallel
+    # group: moe_held experts from moe_held_offset (0 = all of them)
+    moe_held: int = 0
+    moe_held_offset: int = 0
     # FSDP-shard expert d_ff over 'data' — required only when expert
     # params exceed what the model axis alone can hold (arctic-480b).
     # Costs a per-layer weight all-gather; see EXPERIMENTS.md §Perf.
@@ -71,7 +77,9 @@ class TransformerConfig(NamedTuple):
             return None
         return MoEConfig(self.n_experts, self.moe_top_k, self.d_model,
                          self.moe_d_ff, self.n_experts_padded,
-                         self.capacity_factor, n_groups=self.moe_groups)
+                         self.capacity_factor, n_groups=self.moe_groups,
+                         norm_topk=self.moe_norm_topk, n_held=self.moe_held,
+                         held_offset=self.moe_held_offset)
 
     def param_count(self) -> int:
         """Analytic parameter count (for MODEL_FLOPS cross-checks)."""
@@ -184,11 +192,13 @@ def param_specs(cfg: TransformerConfig) -> dict:
         lyr["w_down"] = P(None, "model", None)
     if cfg.moe_style != "none":
         fs = "data" if cfg.moe_fsdp else None
+        # held experts are this chip's own share: nothing left to split
+        ex = None if cfg.moe_held else "model"
         lyr["moe"] = {
             "router": P(None, None, None),
-            "w_gate": P(None, "model", None, fs),
-            "w_up": P(None, "model", None, fs),
-            "w_down": P(None, "model", fs, None),
+            "w_gate": P(None, ex, None, fs),
+            "w_up": P(None, ex, None, fs),
+            "w_down": P(None, ex, fs, None),
         }
     if cfg.shared_expert_ff:
         lyr["sh_gate"] = P(None, None, "model")
@@ -268,29 +278,63 @@ def _write_cache(cache: jax.Array, kv: jax.Array, pos: jax.Array) -> jax.Array:
     return jnp.where(onehot, kv.astype(cache.dtype), cache)
 
 
-def _ffn_block(x, lp, cfg: TransformerConfig):
+def _ffn_block(x, lp, cfg: TransformerConfig, experts=None):
+    """-> (x, aux loss, routed).  ``experts``: (every layer's expert
+    stacks, this layer's index) for the drop-free dispatch to read in
+    place, whose ``routed`` counts the held-expert assignments and active
+    held experts; or None for the capacity dispatch (``_expert_stacks``
+    chooses)."""
     b, s, d = x.shape
     h = L.rms_norm(x, lp["ln2"])
     aux = jnp.zeros((), jnp.float32)
+    routed = jnp.zeros((2,), jnp.int32)
     out = jnp.zeros_like(h)
     if cfg.moe_style in ("none", "parallel"):
         out = out + L.swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
     if cfg.moe_style != "none":
         flat = h.reshape(b * s, d)
-        moe_out, aux = moe_ffn(flat, lp["moe"], cfg.moe_cfg)
+        if experts is None:
+            moe_out, aux = moe_ffn(flat, lp["moe"], cfg.moe_cfg)
+        else:
+            stacks, layer = experts
+            moe_out, routed = moe_ffn_serving(
+                flat, dict(lp["moe"], **stacks), cfg.moe_cfg, layer=layer)
         out = out + moe_out.reshape(b, s, d)
     if cfg.shared_expert_ff:
         gate = jax.nn.sigmoid(
             jnp.einsum("bsd,dz->bsz", h, lp["sh_gate_w"]).astype(jnp.float32))
         sh = L.swiglu(h, lp["sh_gate"], lp["sh_up"], lp["sh_down"])
         out = out + (sh * gate.astype(sh.dtype))
-    return x + out, aux
+    return x + out, aux, routed
 
 
-def _layer(x, lp, cfg, positions, mode, cache=None, kv_len=None):
+def _layer(x, lp, cfg, positions, mode, cache=None, kv_len=None,
+           experts=None):
+    """-> (x, new cache, aux loss, routed) (``_ffn_block``)."""
     x, new_cache = _attn_block(x, lp, cfg, positions, mode, cache, kv_len)
-    x, aux = _ffn_block(x, lp, cfg)
-    return x, new_cache, aux
+    x, aux, routed = _ffn_block(x, lp, cfg, experts)
+    return x, new_cache, aux, routed
+
+
+_STACKS = ("w_gate", "w_up", "w_down")
+
+
+def _expert_stacks(layers: dict, cfg: TransformerConfig, mode):
+    """(layer params to slice per layer, expert stacks or None).
+
+    Prefill and decode dispatch drop-free, and the routed experts'
+    stacks stay whole: the grouped products read a layer's experts at
+    its index, so the layer loop does not copy each layer's experts out
+    of the stacked weights.  Training, and stacks that ``param_specs``
+    splits over a 'model' mesh axis (every expert held), keep the
+    capacity dispatch (None): GSPMD cannot partition the grouped-product
+    kernel's custom call."""
+    split = not cfg.moe_held and (mesh_axis_size("model") or 1) > 1
+    if cfg.moe_style == "none" or mode == "train" or split:
+        return layers, None
+    moe = layers["moe"]
+    rest = {k: v for k, v in moe.items() if k not in _STACKS}
+    return dict(layers, moe=rest), {k: moe[k] for k in _STACKS}
 
 
 def _scan_layers(params, x, cfg: TransformerConfig, positions, mode):
@@ -298,13 +342,18 @@ def _scan_layers(params, x, cfg: TransformerConfig, positions, mode):
     fn = _layer
     if cfg.remat and mode == "train":
         fn = jax.checkpoint(_layer, static_argnums=(2, 4))
+    layers, stacks = _expert_stacks(params["layers"], cfg, mode)
+
+    def experts(i):
+        return None if stacks is None else (stacks, i)
 
     if cfg.layers_impl == "unroll":
         aux = jnp.zeros((), jnp.float32)
         caches = []
         for i in range(cfg.n_layers):
-            lp = jax.tree.map(lambda a: a[i], params["layers"])
-            x, cache_i, aux_i = fn(x, lp, cfg, positions, mode)
+            lp = jax.tree.map(lambda a: a[i], layers)
+            x, cache_i, aux_i, _ = fn(x, lp, cfg, positions, mode,
+                                      experts=experts(i))
             aux = aux + aux_i
             caches.append(cache_i)
         if mode == "prefill":
@@ -313,13 +362,15 @@ def _scan_layers(params, x, cfg: TransformerConfig, positions, mode):
             caches = None
         return x, caches, aux
 
-    def body(carry, lp):
+    def body(carry, xs):
         h, aux_tot = carry
-        h, new_cache, aux = fn(h, lp, cfg, positions, mode)
+        lp, i = xs
+        h, new_cache, aux, _ = fn(h, lp, cfg, positions, mode,
+                                  experts=experts(i))
         return (h, aux_tot + aux), new_cache
 
     (x, aux), caches = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                    params["layers"])
+                                    (layers, jnp.arange(cfg.n_layers)))
     return x, caches, aux
 
 
@@ -407,32 +458,43 @@ def prefill(params: dict, tokens: jax.Array, cfg: TransformerConfig,
 
 def _decode_layers(params: dict, token: jax.Array, k: jax.Array,
                    v: jax.Array, positions: jax.Array, kv_len,
-                   cfg: TransformerConfig
-                   ) -> tuple[jax.Array, jax.Array, jax.Array]:
+                   cfg: TransformerConfig):
     """Shared one-token layer loop.  token [B], k/v [L, B, S, KV, H],
     positions [B, 1], kv_len scalar or [B] -> (hidden [B, D], k_new,
-    v_new).  Every op is row-parallel over B."""
+    v_new, routed).  Every op is row-parallel over B.  ``routed``: for
+    a MoE model int32 [2], the step's held-expert assignments and active
+    held experts summed over layers; None for a dense one."""
     x = params["embed"][token[:, None]].astype(cfg.dtype)   # [B, 1, D]
+    routed = jnp.zeros((2,), jnp.int32)
+    layers, stacks = _expert_stacks(params["layers"], cfg, "decode")
+
+    def experts(i):
+        return None if stacks is None else (stacks, i)
 
     if cfg.layers_impl == "unroll":
         ks, vs = [], []
         for i in range(cfg.n_layers):
-            lp = jax.tree.map(lambda a: a[i], params["layers"])
-            x, (k_i, v_i), _ = _layer(x, lp, cfg, positions, "decode",
-                                      (k[i], v[i]), kv_len)
+            lp = jax.tree.map(lambda a: a[i], layers)
+            x, (k_i, v_i), _, r = _layer(x, lp, cfg, positions, "decode",
+                                         (k[i], v[i]), kv_len, experts(i))
+            routed = routed + r
             ks.append(k_i)
             vs.append(v_i)
         k_new, v_new = jnp.stack(ks), jnp.stack(vs)
     else:
         def body(carry, xs):
-            h = carry
-            lp, kc, vc = xs
-            h, new_cache, _ = _layer(h, lp, cfg, positions, "decode",
-                                     (kc, vc), kv_len)
-            return h, new_cache
+            h, tot = carry
+            lp, kc, vc, i = xs
+            h, new_cache, _, r = _layer(h, lp, cfg, positions, "decode",
+                                        (kc, vc), kv_len, experts(i))
+            return (h, tot + r), new_cache
 
-        x, (k_new, v_new) = jax.lax.scan(body, x, (params["layers"], k, v))
-    return L.rms_norm(x[:, 0], params["final_norm"]), k_new, v_new
+        (x, routed), (k_new, v_new) = jax.lax.scan(
+            body, (x, routed),
+            (layers, k, v, jnp.arange(cfg.n_layers)))
+    hidden = L.rms_norm(x[:, 0], params["final_norm"])
+    return (hidden, k_new, v_new,
+            None if cfg.moe_style == "none" else routed)
 
 
 def decode_step(params: dict, token: jax.Array, cache: KVCache,
@@ -445,22 +507,22 @@ def decode_step(params: dict, token: jax.Array, cache: KVCache,
     b = token.shape[0]
     kv_len = cache.length + 1
     positions = jnp.full((b, 1), cache.length, jnp.int32)
-    hidden, k_new, v_new = _decode_layers(params, token, cache.k, cache.v,
-                                          positions, kv_len, cfg)
+    hidden, k_new, v_new, _ = _decode_layers(params, token, cache.k,
+                                             cache.v, positions, kv_len, cfg)
     return hidden, KVCache(k_new, v_new, kv_len)
 
 
 def decode_step_pooled(params: dict, token: jax.Array, k: jax.Array,
                        v: jax.Array, lengths: jax.Array,
-                       cfg: TransformerConfig
-                       ) -> tuple[jax.Array, jax.Array, jax.Array]:
+                       cfg: TransformerConfig):
     """One decode step over a slot pool with PER-ROW cache lengths.
 
     The continuous-batching counterpart of :func:`decode_step`: rows are
     independent streams at different depths, so each row reads its own
     valid prefix and writes its new KV at its own position.  token [B]
     int32, k/v [L, B, S_max, KV, H] slabs, lengths [B] int32 (current
-    valid prefix per slot) -> (hidden [B, D], k_new, v_new).
+    valid prefix per slot) -> (hidden [B, D], k_new, v_new, routed), the
+    step's routing counts as :func:`_decode_layers` gives them.
 
     Row ``i`` computes exactly what :func:`decode_step` computes for a
     batch-1 cache of the same width ``S_max`` — every op is row-parallel —
@@ -475,13 +537,13 @@ def decode_step_pooled(params: dict, token: jax.Array, k: jax.Array,
 def decode_step_paged(params: dict, token: jax.Array, k_arena: jax.Array,
                       v_arena: jax.Array, page_table: jax.Array,
                       lengths: jax.Array, cfg: TransformerConfig,
-                      max_len: int
-                      ) -> tuple[jax.Array, jax.Array, jax.Array]:
+                      max_len: int):
     """:func:`decode_step_pooled` over PAGED KV storage.
 
     token [B] int32, k/v arenas [L, n_pages, page_tokens, KV, H],
     page_table [B, pages_per_slot] int32 (0 = unmapped -> the reserved
-    scratch page), lengths [B] int32 -> (hidden [B, D], k_arena, v_arena).
+    scratch page), lengths [B] int32 -> (hidden [B, D], k_arena, v_arena,
+    routed).
 
     Bit-identity with the dense layout is by construction: each row's
     pages are gathered IN ORDER into a contiguous view sliced to exactly
@@ -520,7 +582,7 @@ def decode_step_paged(params: dict, token: jax.Array, k_arena: jax.Array,
         return arena[:, page_table].reshape(n_l, b, n_pp * p,
                                             n_kv, h_dim)[:, :, :w]
 
-    hidden, k_new, v_new = _decode_layers(
+    hidden, k_new, v_new, routed = _decode_layers(
         params, token, view(k_arena), view(v_arena),
         lengths[:, None].astype(jnp.int32), lengths + 1, cfg)
 
@@ -534,4 +596,4 @@ def decode_step_paged(params: dict, token: jax.Array, k_arena: jax.Array,
         k_new[:, rows, wpos].astype(k_arena.dtype))
     v_arena = v_arena.at[:, dest, off].set(
         v_new[:, rows, wpos].astype(v_arena.dtype))
-    return hidden, k_arena, v_arena
+    return hidden, k_arena, v_arena, routed

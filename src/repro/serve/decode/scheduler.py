@@ -127,12 +127,17 @@ class DecodeStats(NamedTuple):
     n_shed_kv_oom: int = 0       # sessions shed: paged arena exhausted
     join_wait_s_total: float = 0.0   # sum of submit -> start of admission
     n_joined: int = 0            # sessions joined to a slot
+    # MoE models, running totals over collected fused steps and layers
+    # (every row of a step, parked slots included, as the experts ran)
+    moe_assignments: int = 0     # (token, held expert) assignments
+    moe_active_experts: int = 0  # held experts that got at least one
 
 
 class _Inflight(NamedTuple):
     ho: object                   # HeadOutput of the dispatched step
     snapshot: list               # [(slot, session)] active at dispatch
     t0: float
+    routed: object = None        # the step's routing counts (MoE), or None
 
 
 class DecodeScheduler:
@@ -221,6 +226,8 @@ class DecodeScheduler:
         self._occupancy_sum = 0.0
         self._join_wait_s = 0.0
         self._n_joined = 0
+        self._moe_assignments = 0
+        self._moe_active = 0
         # bounded token-latency telemetry (was: unbounded TTFT/ITL lists)
         self.obs = obs.MetricsRegistry(scope_prefix="decode")
         self._h_ttft = self.obs.histogram(
@@ -462,7 +469,7 @@ class DecodeScheduler:
         step = self.engine.decode_logits(self.head, self._tag, self._body,
                                          epoch=self._epoch)
         t0 = time.perf_counter()
-        tok_next, ho, k_new, v_new = step(
+        tok_next, ho, k_new, v_new, routed = step(
             self.params, self.tok, *self.pool.step_operands())
         self.tok = tok_next                      # device-to-device feedback
         self.pool.k, self.pool.v = k_new, v_new
@@ -480,11 +487,17 @@ class DecodeScheduler:
         with self._lock:
             self._n_steps += 1
             self._occupancy_sum += len(active) / self.max_streams
-        return _Inflight(ho, snapshot, t0)
+        return _Inflight(ho, snapshot, t0, routed)
 
     # --------------------------------------------------------------- collect --
     def _collect(self, item: _Inflight) -> None:
-        ids = np.asarray(item.ho.ids)            # blocks until step done
+        if item.routed is None:
+            ids = np.asarray(item.ho.ids)        # blocks until step done
+        else:                                    # one fetch for both
+            ids, routed = jax.device_get((item.ho.ids, item.routed))
+            with self._lock:
+                self._moe_assignments += int(routed[0])
+                self._moe_active += int(routed[1])
         t1 = time.perf_counter()
         for slot, sess in item.snapshot:
             if sess.finished:                    # retired after dispatch:
@@ -601,6 +614,8 @@ class DecodeScheduler:
             self._occupancy_sum = 0.0
             self._join_wait_s = 0.0
             self._n_joined = 0
+            self._moe_assignments = 0
+            self._moe_active = 0
             self._h_ttft.reset()
             self._h_itl.reset()
             self._t_first = None
@@ -648,4 +663,6 @@ class DecodeScheduler:
                 n_shed_kv_oom=self._n_shed_kv_oom,
                 join_wait_s_total=self._join_wait_s,
                 n_joined=self._n_joined,
+                moe_assignments=self._moe_assignments,
+                moe_active_experts=self._moe_active,
             )

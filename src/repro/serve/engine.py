@@ -558,14 +558,16 @@ class Engine:
         the LSS kinds, so the WOL ranking inside the token loop is the
         same kernel path the score buckets use.
 
-        ``body(params, tok, *state) -> (hidden [B, d], k_new, v_new)``
-        where ``state`` is the pool layout's cache operands — dense
-        ``(k, v, lengths)``, paged ``(k, v, page_table, lengths)``; the
-        returned step maps the same signature to ``(tok_next [B] int32,
-        HeadOutput, k_new, v_new)`` with the next-token feedback computed
-        IN-program, so a decode loop can chain steps device-to-device
-        without a host round trip.  ``tag`` names the compile shape (the
-        scheduler uses "decode[SxW]", paged "decode[SxW,pagedP]") and
+        ``body(params, tok, *state) -> (hidden [B, d], k_new, v_new,
+        routed)`` where ``state`` is the pool layout's cache operands —
+        dense ``(k, v, lengths)``, paged ``(k, v, page_table, lengths)`` —
+        and ``routed`` the step's routing counts (None for a dense
+        model); the returned step maps the same signature to
+        ``(tok_next [B] int32, HeadOutput, k_new, v_new, routed)`` with
+        the next-token feedback computed IN-program, so a decode loop can
+        chain steps device-to-device without a host round trip.  ``tag``
+        names the compile shape (the scheduler uses "decode[SxW]", paged
+        "decode[SxW,pagedP]") and
         keys the shared jitted-step cache — compile counts land in
         ``compile_counts[(kind, tag)]`` next to the score buckets.  LSS
         decode steps live in their index epoch's table (``epoch`` pins a
@@ -599,11 +601,11 @@ class Engine:
                     self.compile_counts[key] = \
                         self.compile_counts.get(key, 0) + 1
                     state, ops = rest[:-n_ops], rest[-n_ops:]
-                    hidden, k_new, v_new = body(params, tok, *state)
+                    hidden, k_new, v_new, routed = body(params, tok, *state)
                     ho = head.with_operands(hidden.astype(jnp.float32),
                                             *ops)
                     tok_next = jnp.maximum(ho.ids[:, 0], 0).astype(jnp.int32)
-                    return tok_next, ho, k_new, v_new
+                    return tok_next, ho, k_new, v_new, routed
 
                 _name_step(raw_step, "decode_step", kind)
 

@@ -130,6 +130,10 @@ class RuntimeStats(NamedTuple):
     n_dispatched: int = 0            # scoring requests dispatched
     join_wait_s_total: float = 0.0   # sum of submit -> admission, decode
     n_joined: int = 0                # decode sessions joined to a slot
+    # ------------------------------------------- decode steps, routing --
+    n_decode_steps: int = 0          # fused decode steps dispatched
+    moe_assignments: int = 0         # held-expert assignments (MoE)
+    moe_active_experts: int = 0      # active held experts (MoE)
 
 
 def _paced_submit(n: int, qps: float, seed: int, submit
@@ -787,6 +791,9 @@ class AsyncRuntime:
                 kv_peak_pages=ds.kv_peak_pages,
                 join_wait_s_total=ds.join_wait_s_total,
                 n_joined=ds.n_joined,
+                n_decode_steps=ds.n_steps,
+                moe_assignments=ds.moe_assignments,
+                moe_active_experts=ds.moe_active_experts,
             )
             return RuntimeStats(**decode,
                 n_submitted=self._n_submitted,

@@ -37,7 +37,7 @@ def _fitted_index(m, d, k, l, seed=0, bucket_major=True):
 # ------------------------------------------------------------ registry --
 
 def test_ops_registered_with_all_impls():
-    for name in ("simhash_codes", "bucket_logits", "lss_topk"):
+    for name in ("simhash_codes", "bucket_logits", "lss_topk", "moe_gmm"):
         op = registry.get_op(name)
         assert set(op.impls) == {"ref", "pallas", "pallas_interpret"}, name
 

@@ -2,8 +2,9 @@
 
 Covers: vocab-sharded LSS == single-device LSS; sharded train step runs;
 gradient compression all-reduce matches fp32 mean within error-feedback
-bounds; mini dry-run (lower+compile) for one LM and one recsys cell on a
-(2, 4) debug mesh.
+bounds; mini dry-run (lower+compile) for one LM, one recsys and one GNN
+cell and a MoE prefill and decode cell (capacity dispatch over experts
+split across 'model') on a (2, 4) debug mesh.
 """
 
 import os
@@ -115,17 +116,26 @@ assert float(jnp.abs(new_err["w"]).max()) > 0
 print("COMPRESSION-OK")
 
 # ---- 4. mini dry-run: lower + compile one cell per family --------------
+from repro.kernels import registry
 from repro.launch.steps import build_cell
 for arch, shape in (("qwen2-0.5b", "decode_32k"), ("deepfm", "serve_p99"),
-                    ("gcn-cora", "molecule")):
+                    ("gcn-cora", "molecule"),
+                    ("qwen2-moe-a2.7b", "prefill_32k"),
+                    ("qwen2-moe-a2.7b", "decode_32k")):
     # shrink: reuse the production builder on the debug mesh
+    lm = arch.startswith("qwen2")
     cell = build_cell(arch, shape, mesh, lm_layers=2) \
-        if arch == "qwen2-0.5b" else build_cell(arch, shape, mesh)
+        if lm else build_cell(arch, shape, mesh)
+    registry.reset_dispatch_log()
     with jax.set_mesh(mesh):
         compiled = jax.jit(cell.fn, in_shardings=cell.in_shardings
                            ).lower(*cell.args).compile()
     assert compiled.cost_analysis()["flops"] > 0
-    print(f"MINIDRY-{arch}-OK")
+    # experts split over 'model' keep the capacity dispatch: no grouped
+    # products, whose kernel GSPMD cannot partition
+    assert all(op != "moe_gmm" for op, _ in registry.dispatch_log()), \
+        (arch, shape)
+    print(f"MINIDRY-{arch}-{shape}-OK")
 print("ALL-OK")
 """
 

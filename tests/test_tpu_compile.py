@@ -2,8 +2,10 @@
 
 The TPU compiler is installed even where no chip is attached, so these
 tests lower the fused ``lss_topk`` kernel (and the ``simhash_codes``
-kernel the IUL fit retrieves through) at qwen2-0.5b's head widths and
-compile them for one chip of a ``v5e:2x2`` topology.  One more lowers
+kernel the IUL fit retrieves through) at qwen2-0.5b's head widths, and
+``lss_topk`` again and the ``moe_gmm`` grouped product at
+qwen2-moe-a2.7b's (d 2,048, experts of 1,408, 15 held), and compile them
+for one chip of a ``v5e:2x2`` topology.  One more lowers
 the LSS head's whole jitted step, to see that an index stored in the
 kernel's aligned layout reaches the kernel without a slab-sized pad or
 copy, as logical storage does not.  Mosaic refuses
@@ -31,6 +33,7 @@ from repro.core.lss import LSSIndex
 from repro.core.tables import LSSTables
 from repro.kernels.lss_topk.kernel import lss_topk_pallas
 from repro.kernels.lss_topk.ops import lss_topk_vmem_bytes, lss_topk_vmem_limit
+from repro.kernels.moe_gmm.ops import moe_gmm_op
 from repro.kernels.simhash_codes.kernel import simhash_codes_pallas
 from repro.serve.heads import make_lss_head
 
@@ -93,6 +96,52 @@ def test_lss_topk_compiles_for_v5e(one_chip, no_persistent_cache, block_q,
         dedup=dedup, vmem_limit_bytes=limit).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert est < limit
+
+
+# qwen2-moe-a2.7b: d_aug 2,049 -> 2,176 lanes, the same K, L and P
+D_MOE = 2176
+
+
+@pytest.mark.parametrize("block_q", [1, 8])
+def test_lss_topk_compiles_for_v5e_at_qwen2_moe_width(
+        one_chip, no_persistent_cache, block_q):
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    est = lss_topk_vmem_bytes(N_TABLES * CAP, D_MOE, CAP, block_q=block_q,
+                              dedup="quadratic", kl=K_BITS * N_TABLES)
+    compiled = lss_topk_pallas.lower(
+        arg((block_q, D_MOE), jnp.float32),
+        arg((D_MOE, K_BITS * N_TABLES), jnp.float32),
+        arg((N_SLABS, 1, CAP), jnp.int32),
+        arg((N_SLABS, CAP, D_MOE), jnp.float32), None,
+        k_bits=K_BITS, n_tables=N_TABLES, top_k=TOP_K, block_q=block_q,
+        dedup="quadratic", vmem_limit_bytes=lss_topk_vmem_limit(est)
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows,k,n", [
+    (128, 2048, 1408),      # a decode step's 32 rows x top-4: gate / up
+    (128, 1408, 2048),      # down
+    (4096, 2048, 1408),     # a 1,024-token prefill bucket
+])
+def test_moe_gmm_compiles_for_v5e(one_chip, no_persistent_cache, rows, k,
+                                  n):
+    """The grouped product over 15 held experts of qwen2-moe-a2.7b, read
+    in place from six layers' stacked weights, as the ``pallas`` impl
+    calls it; its custom call keeps the name the benchmark's reader finds
+    it by."""
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    impl = moe_gmm_op.impls["pallas"]
+    hlo = jax.jit(impl).lower(arg((rows, k), jnp.bfloat16),
+                              arg((6, 15, k, n), jnp.bfloat16),
+                              arg((15,), jnp.int32),
+                              arg((1,), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert re.search(r"%moe_gmm_pallas(\.\d+)? = ", hlo)
 
 
 def test_simhash_codes_compiles_for_v5e(one_chip, no_persistent_cache):

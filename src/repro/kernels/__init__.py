@@ -21,5 +21,6 @@ from repro.kernels.simhash_codes import simhash_codes
 from repro.kernels.bucket_logits import bucket_logits
 from repro.kernels.lss_topk import lss_topk
 from repro.kernels.moe_gmm import moe_gmm
+from repro.kernels.kv_write import kv_write
 __all__ = ["registry", "simhash_codes", "bucket_logits", "lss_topk",
-           "moe_gmm"]
+           "moe_gmm", "kv_write"]

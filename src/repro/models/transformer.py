@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P  # noqa: F401
 
+from repro.kernels.kv_write import kv_write
 from repro.models import layers as L
 from repro.models.moe import (MoEConfig, init_moe_params, moe_ffn,
                               moe_ffn_serving)
@@ -219,7 +220,10 @@ def param_specs(cfg: TransformerConfig) -> dict:
 
 def _attn_block(x, lp, cfg: TransformerConfig, positions, mode,
                 cache=None, kv_len=None):
-    """Shared attention block. mode: train | prefill | decode."""
+    """Shared attention block. mode: train | prefill | decode.  In decode
+    ``cache`` is (every layer's k cache, every layer's v cache, this
+    layer's index), and the new cache is both caches with the step
+    written in."""
     b, s, d = x.shape
     h = L.rms_norm(x, lp["ln1"])
     q = jnp.einsum("bsd,dn->bsn", h, lp["wq"])
@@ -252,11 +256,12 @@ def _attn_block(x, lp, cfg: TransformerConfig, positions, mode,
     k = L.apply_rope(k, positions, cfg.rope_base)
 
     if mode == "decode":
+        k_all, v_all, layer = cache
         pos = kv_len - 1                           # write slot (traced)
-        k_cache = _write_cache(cache[0], k, pos)
-        v_cache = _write_cache(cache[1], v, pos)
+        k_all, v_all, k_cache, v_cache = _write_cache(k_all, v_all, k, v,
+                                                      layer, pos)
         out = L.attention_decode(q, k_cache, v_cache, kv_len)
-        new_cache = (k_cache, v_cache)
+        new_cache = (k_all, v_all)
     else:
         out = L.attention_blockwise(q, k, v, causal=True,
                                     kv_chunk=cfg.kv_chunk,
@@ -266,16 +271,15 @@ def _attn_block(x, lp, cfg: TransformerConfig, positions, mode,
     return x + jnp.einsum("bsn,nd->bsd", out, lp["wo"]), new_cache
 
 
-def _write_cache(cache: jax.Array, kv: jax.Array, pos: jax.Array) -> jax.Array:
-    """Write the [B, 1, KV, H] step into cache[:, pos] (traced pos, scalar
-    or [B] for per-row write positions under continuous batching)."""
-    pos = jnp.asarray(pos)
-    if pos.ndim == 0:
-        onehot = (jnp.arange(cache.shape[1]) == pos)[None, :, None, None]
-    else:
-        onehot = (jnp.arange(cache.shape[1])[None, :]
-                  == pos[:, None])[:, :, None, None]
-    return jnp.where(onehot, kv.astype(cache.dtype), cache)
+def _write_cache(k_cache: jax.Array, v_cache: jax.Array, k: jax.Array,
+                 v: jax.Array, layer, pos: jax.Array):
+    """Write the [B, 1, KV, H] step into layer ``layer`` of every layer's
+    caches [L, B, S, KV, H] at ``pos`` (traced, scalar or [B] for per-row
+    write positions under continuous batching) -> (k_cache, v_cache, and
+    the layer's [B, S, KV, H] slabs holding the step).  A row at
+    ``pos == S`` writes nothing."""
+    return kv_write(k_cache, v_cache, k[:, 0], v[:, 0], layer,
+                    jnp.broadcast_to(pos, k.shape[:1]))
 
 
 def _ffn_block(x, lp, cfg: TransformerConfig, experts=None):
@@ -461,9 +465,12 @@ def _decode_layers(params: dict, token: jax.Array, k: jax.Array,
                    cfg: TransformerConfig):
     """Shared one-token layer loop.  token [B], k/v [L, B, S, KV, H],
     positions [B, 1], kv_len scalar or [B] -> (hidden [B, D], k_new,
-    v_new, routed).  Every op is row-parallel over B.  ``routed``: for
-    a MoE model int32 [2], the step's held-expert assignments and active
-    held experts summed over layers; None for a dense one."""
+    v_new, routed).  Every op is row-parallel over B.  The layer loop
+    carries k/v and each layer writes its new rows into them in place
+    (``_write_cache``), so a donated cache is never rebuilt as the scan's
+    output and copied back.  ``routed``: for a MoE model int32 [2], the
+    step's held-expert assignments and active held experts summed over
+    layers; None for a dense one."""
     x = params["embed"][token[:, None]].astype(cfg.dtype)   # [B, 1, D]
     routed = jnp.zeros((2,), jnp.int32)
     layers, stacks = _expert_stacks(params["layers"], cfg, "decode")
@@ -472,29 +479,23 @@ def _decode_layers(params: dict, token: jax.Array, k: jax.Array,
         return None if stacks is None else (stacks, i)
 
     if cfg.layers_impl == "unroll":
-        ks, vs = [], []
         for i in range(cfg.n_layers):
             lp = jax.tree.map(lambda a: a[i], layers)
-            x, (k_i, v_i), _, r = _layer(x, lp, cfg, positions, "decode",
-                                         (k[i], v[i]), kv_len, experts(i))
+            x, (k, v), _, r = _layer(x, lp, cfg, positions, "decode",
+                                     (k, v, i), kv_len, experts(i))
             routed = routed + r
-            ks.append(k_i)
-            vs.append(v_i)
-        k_new, v_new = jnp.stack(ks), jnp.stack(vs)
     else:
         def body(carry, xs):
-            h, tot = carry
-            lp, kc, vc, i = xs
-            h, new_cache, _, r = _layer(h, lp, cfg, positions, "decode",
-                                        (kc, vc), kv_len, experts(i))
-            return (h, tot + r), new_cache
+            h, kc, vc, tot = carry
+            lp, i = xs
+            h, (kc, vc), _, r = _layer(h, lp, cfg, positions, "decode",
+                                       (kc, vc, i), kv_len, experts(i))
+            return (h, kc, vc, tot + r), None
 
-        (x, routed), (k_new, v_new) = jax.lax.scan(
-            body, (x, routed),
-            (layers, k, v, jnp.arange(cfg.n_layers)))
+        (x, k, v, routed), _ = jax.lax.scan(
+            body, (x, k, v, routed), (layers, jnp.arange(cfg.n_layers)))
     hidden = L.rms_norm(x[:, 0], params["final_norm"])
-    return (hidden, k_new, v_new,
-            None if cfg.moe_style == "none" else routed)
+    return hidden, k, v, None if cfg.moe_style == "none" else routed
 
 
 def decode_step(params: dict, token: jax.Array, cache: KVCache,
@@ -569,7 +570,7 @@ def decode_step_paged(params: dict, token: jax.Array, k_arena: jax.Array,
     The new KV row is scattered back into each row's current write page
     (page ``lengths // p``, offset ``lengths % p``).  Rows that must not
     write — parked slots and rows at ``lengths == max_len`` (where the
-    dense one-hot write falls off the end of the slab) — are redirected
+    dense write falls off the end of the slab) — are redirected
     to scratch page 0, so a freed slot's in-flight step can never
     corrupt a recycled page.  All shapes are static: joins, leaves, and
     page-table churn cost zero recompiles.

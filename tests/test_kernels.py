@@ -136,3 +136,35 @@ def test_lss_topk_warns_once_past_vmem_budget():
     with warnings.catch_warnings():
         warnings.simplefilter("error")
         ops.lss_topk(q, theta, tids, wb, top_k=3, impl="ref")
+
+
+@pytest.mark.parametrize("s,window_bytes", [(256, None), (256, 2 ** 16),
+                                            (96, None)],
+                         ids=["two-windows", "row-per-step", "unaligned"])
+def test_kv_write_lanes_kernel_matches_ref(monkeypatch, s, window_bytes):
+    """The lanes kernel (interpret mode) writes exactly the per-row
+    updates: positions at both edges of a 128-lane window, at S - 1 and at
+    S (which writes nothing), one or all rows per grid step, and a
+    sequence 128 does not divide (one window of all of S)."""
+    from repro.kernels.kv_write import ops
+    if window_bytes:
+        monkeypatch.setattr(ops, "WINDOW_BYTES", window_bytes)
+    n_l, b, n_kv, h = 3, 6, 2, 64
+    shape = (n_l, b, s, n_kv, h)
+    k = jax.random.normal(jax.random.PRNGKey(0), shape, jnp.bfloat16)
+    v = jax.random.normal(jax.random.PRNGKey(1), shape, jnp.bfloat16)
+    k_rows = jax.random.normal(jax.random.PRNGKey(2), (b, n_kv, h))
+    v_rows = jax.random.normal(jax.random.PRNGKey(3), (b, n_kv, h))
+    pos = jnp.array([0, min(127, s - 2), min(128, s - 3), s - 1, s, 5],
+                    jnp.int32)
+    want = ops.kv_write(k, v, k_rows, v_rows, 1, pos, impl="ref")
+    got = ops.kv_write(k, v, k_rows, v_rows, 1, pos,
+                       impl="pallas_interpret")
+    for g, w, c in zip(got, want, (k, v)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        assert not np.array_equal(np.asarray(g), np.asarray(c))
+    ref_k = np.asarray(k).copy()
+    for r, p in enumerate(np.asarray(pos)):
+        if p < s:
+            ref_k[1, r, p] = np.asarray(k_rows[r].astype(jnp.bfloat16))
+    np.testing.assert_array_equal(np.asarray(want[0]), ref_k)

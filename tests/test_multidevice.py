@@ -135,6 +135,12 @@ for arch, shape in (("qwen2-0.5b", "decode_32k"), ("deepfm", "serve_p99"),
     # products, whose kernel GSPMD cannot partition
     assert all(op != "moe_gmm" for op, _ in registry.dispatch_log()), \
         (arch, shape)
+    # a KV cache split over the mesh takes the layer select, which GSPMD
+    # partitions elementwise, at every head width: never the kv_write
+    # kernel's custom call, nor a row scatter into the split cache
+    if shape.startswith("decode"):
+        assert {c for op, c in registry.dispatch_log()
+                if op == "decode.kv_write"} == {"layer_select"}, arch
     print(f"MINIDRY-{arch}-{shape}-OK")
 print("ALL-OK")
 """

@@ -8,7 +8,9 @@ qwen2-moe-a2.7b's (d 2,048, experts of 1,408, 15 held), and compile them
 for one chip of a ``v5e:2x2`` topology.  One more lowers
 the LSS head's whole jitted step, to see that an index stored in the
 kernel's aligned layout reaches the kernel without a slab-sized pad or
-copy, as logical storage does not.  Mosaic refuses
+copy, as logical storage does not; another the pooled decode step at both
+decode widths, to see that it writes its KV rows into the donated cache
+without copying a layer's slab.  Mosaic refuses
 what interpret mode accepts: unaligned DMA slices, scalar stores to
 VMEM, bool transposes, rank-changing shape casts, and more VMEM than the
 kernel asks for.  Nothing runs, so these say nothing about results.
@@ -27,14 +29,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import qwen2_0_5b
 from repro.core.lss import LSSIndex
 from repro.core.tables import LSSTables
+from repro.kernels import registry
 from repro.kernels.lss_topk.kernel import lss_topk_pallas
 from repro.kernels.lss_topk.ops import lss_topk_vmem_bytes, lss_topk_vmem_limit
 from repro.kernels.moe_gmm.ops import moe_gmm_op
 from repro.kernels.simhash_codes.kernel import simhash_codes_pallas
+from repro.models import transformer as T
 from repro.serve.heads import make_lss_head
 
 # qwen2-0.5b's LSS head: d_aug 897 -> 1024 lanes, K=10 bits, L=1 table,
@@ -172,16 +178,15 @@ def _lss_index(one_chip, layout: str) -> LSSIndex:
         (N_TABLES, 2 ** K_BITS, CAP_LOGICAL, D_AUG), jnp.float32))
 
 
-_MOVE = re.compile(r"= \w+\[([\d,]*)\]\S* (?:pad|copy)\(")
-
-
-def _slab_sized_moves(hlo: str, n_elems: int) -> list[str]:
-    """The pad and copy instructions of ``hlo`` with at least
-    ``n_elems`` elements (a copy's result is its operand's size, and a
-    pad's is larger)."""
+def _slab_sized_moves(hlo: str, n_elems: int,
+                      ops: str = "pad|copy") -> list[str]:
+    """The instructions of ``hlo`` named by ``ops`` (pad and copy by
+    default) with at least ``n_elems`` elements (a copy's or transpose's
+    result is its operand's size, and a pad's is larger)."""
+    move = re.compile(rf"= \w+\[([\d,]*)\]\S* (?:{ops})\(")
     found = []
     for line in hlo.splitlines():
-        m = _MOVE.search(line)
+        m = move.search(line)
         if m and np.prod([int(x) for x in m.group(1).split(",") if x]
                          ) >= n_elems:
             found.append(line.strip())
@@ -209,3 +214,157 @@ def test_lss_head_step_moves_no_slab_tensor(one_chip, no_persistent_cache,
         assert temp < N_SLABS * CAP * D * 4
     else:
         assert any(" pad(" in line for line in moves), moves
+
+
+# the decode cells' attention: qwen2-0.5b's program config (24 layers, KV
+# 2 x 64), and a dense stand-in with qwen2-moe-a2.7b's chip share (6 layers,
+# MHA 16 x 128; the shared expert's width for the FFN)
+_DECODE_WIDTHS = {
+    "qwen2-0.5b": qwen2_0_5b.CONFIG.model_cfg,
+    "qwen2-moe-a2.7b": T.TransformerConfig(
+        name="qwen2-moe-a2.7b-attention", n_layers=6, d_model=2048,
+        n_heads=16, n_kv_heads=16, head_dim=128, d_ff=5632, vocab=151936,
+        qkv_bias=True, dtype=jnp.bfloat16),
+}
+
+
+def _decode_args(cfg, sharding, b=32, s=1024, cache_sharding=None):
+    """Abstract params, tokens/lengths [b] and one k or v cache [L, b, s,
+    KV, H] for a compile on described chips."""
+    def arg(shape, dtype, sh=sharding):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    shapes = jax.eval_shape(lambda: T.init_params(jax.random.PRNGKey(0), cfg))
+    shardings = (jax.tree.map(lambda _: sharding, shapes)
+                 if cache_sharding is None else
+                 jax.tree.map(lambda spec: NamedSharding(sharding.mesh, spec),
+                              T.param_specs(cfg)))
+    params = jax.tree.map(lambda a, sh: arg(a.shape, a.dtype, sh), shapes,
+                          shardings)
+    kv = arg((cfg.n_layers, b, s, cfg.n_kv_heads, cfg.head_dim),
+             jnp.bfloat16, cache_sharding or sharding)
+    return params, arg((b,), jnp.int32), kv
+
+
+def _aliased(hlo: str) -> set[int]:
+    """The parameters the compiled module aliases to its outputs."""
+    header = hlo.splitlines()[0]          # HloModule ..., input_output_alias
+    return {int(n) for n in re.findall(r"\((\d+), \{\}, \w+-alias\)",
+                                       header)}
+
+
+@pytest.mark.parametrize("step", ["pooled", "scalar"])
+@pytest.mark.parametrize("width", list(_DECODE_WIDTHS))
+def test_decode_step_writes_kv_in_place(one_chip, no_persistent_cache,
+                                        width, step):
+    """The decode step, 32 rows of 1,024 positions, k/v donated, with
+    per-row lengths (the pooled step) or one length (``decode_step``):
+    both slabs alias the step's outputs, no copy or transpose moves a
+    layer's slab, and the temporaries stay below one layer slab's bytes
+    (a step that rewrote the cache as the layer scan's output held two
+    cache-sized copies and a cache's bytes of temporaries); at the MoE
+    widths attention fuses the select of the step's rows."""
+    cfg = _DECODE_WIDTHS[width]
+    params, rows, kv = _decode_args(cfg, one_chip)
+    if step == "pooled":
+        fn, length = T.decode_step_pooled, rows
+    else:
+        def fn(p, tok, k, v, n, cfg):
+            return T.decode_step(p, tok, T.KVCache(k, v, n), cfg)
+        length = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    with registry.use_impl("pallas"):    # as the chip's backend selects
+        compiled = jax.jit(
+            lambda p, tok, k, v, n: fn(p, tok, k, v, n, cfg),
+            donate_argnums=(2, 3), keep_unused=True
+        ).lower(params, rows, kv, kv, length).compile()
+    hlo = compiled.as_text()
+    n_params = len(jax.tree.leaves(params))
+    assert {n_params + 1, n_params + 2} <= _aliased(hlo), hlo[:300]
+    layer_slab = 32 * 1024 * cfg.n_kv_heads * cfg.head_dim
+    assert _slab_sized_moves(hlo, layer_slab, "copy|transpose") == []
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_slab * 2
+    if cfg.head_dim % 128 == 0:
+        # the rows form: attention's score and value fusions read the
+        # step's rows selected into the slab before the write, as they did
+        # when the step rewrote the whole cache (read from the scattered
+        # cache, they compiled to other code and other low bits)
+        slab = f"bf16[32,1024,{cfg.n_kv_heads},{cfg.head_dim}]"
+        assert len(re.findall(rf"= {re.escape(slab)}\S* select\(",
+                              hlo)) == 2
+
+
+@pytest.fixture(scope="module")
+def two_by_four(topo):
+    """The (2, 4) ('data', 'model') mesh over a described v5e:2x4."""
+    from jax.experimental import topologies
+    try:
+        chips = topologies.get_topology_desc(platform="tpu",
+                                             topology_name="v5e:2x4")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x4 topology can be described here: {e}")
+    return jax.sharding.Mesh(np.array(chips.devices).reshape(2, 4),
+                             ("data", "model"))
+
+
+@pytest.mark.parametrize("width", list(_DECODE_WIDTHS))
+def test_split_cache_decode_step_writes_kv_in_place(
+        two_by_four, no_persistent_cache, width):
+    """The pooled decode step under the (2, 4) mesh, its caches split as
+    ``cache_specs`` splits 32 rows (rows over 'data', positions over
+    'model'), k/v donated: the write takes the layer select, both slabs
+    alias, no copy or transpose moves a chip's whole cache, and the
+    temporaries stay below a chip's layer slab.  (A row scatter into the
+    split cache at head_dim 64 relayouted it: four cache-sized copies and
+    100 MB of temporaries a chip.)"""
+    cfg = _DECODE_WIDTHS[width]
+    mesh = two_by_four
+    cache = NamedSharding(mesh, T.cache_specs(cfg, 32).k)
+    params, rows, kv = _decode_args(cfg, NamedSharding(mesh, P()),
+                                    cache_sharding=cache)
+    mark = registry.dispatch_count()
+    with registry.use_impl("pallas"), jax.set_mesh(mesh):
+        compiled = jax.jit(
+            lambda p, tok, k, v, lengths: T.decode_step_pooled(
+                p, tok, k, v, lengths, cfg),
+            donate_argnums=(2, 3), keep_unused=True
+        ).lower(params, rows, kv, kv, rows).compile()
+    assert {c for op, c in registry.dispatch_log(mark)
+            if op == "decode.kv_write"} == {"layer_select"}
+    hlo = compiled.as_text()
+    n_params = len(jax.tree.leaves(params))
+    assert {n_params + 1, n_params + 2} <= _aliased(hlo), hlo[:300]
+    chip_slab = 32 * 1024 * cfg.n_kv_heads * cfg.head_dim // 8
+    assert _slab_sized_moves(hlo, chip_slab * cfg.n_layers,
+                             "copy|transpose") == []
+    assert compiled.memory_analysis().temp_size_in_bytes < chip_slab * 2
+
+
+def test_paged_decode_step_lanes_views_are_bitcasts(one_chip,
+                                                    no_persistent_cache):
+    """The paged decode step at qwen2-0.5b's widths (32 rows of 1,024
+    positions in pages of 16), arenas donated: the write takes the lanes
+    kernel over the gathered view, and neither its [L, B, KV, H, S] view
+    nor the view back is a copy or a transpose; both arenas alias."""
+    cfg = _DECODE_WIDTHS["qwen2-0.5b"]
+    b, s, page = 32, 1024, 16
+    params, rows, _ = _decode_args(cfg, one_chip)
+    arena = jax.ShapeDtypeStruct(
+        (cfg.n_layers, b * s // page + 1, page, cfg.n_kv_heads,
+         cfg.head_dim), jnp.bfloat16, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((b, s // page), jnp.int32,
+                                 sharding=one_chip)
+    mark = registry.dispatch_count()
+    with registry.use_impl("pallas"):
+        compiled = jax.jit(
+            lambda p, tok, k, v, tab, lengths: T.decode_step_paged(
+                p, tok, k, v, tab, lengths, cfg, s),
+            donate_argnums=(2, 3), keep_unused=True
+        ).lower(params, rows, arena, arena, table, rows).compile()
+    assert {c for op, c in registry.dispatch_log(mark)
+            if op == "decode.kv_write"} == {"lanes"}
+    hlo = compiled.as_text()
+    n_params = len(jax.tree.leaves(params))
+    assert {n_params + 1, n_params + 2} <= _aliased(hlo), hlo[:300]
+    lanes_view = f"{cfg.n_layers},{b},{cfg.n_kv_heads},{cfg.head_dim},{s}"
+    assert not re.search(
+        rf"= bf16\[{lanes_view}\]\S* (?:copy|transpose)\(", hlo)
